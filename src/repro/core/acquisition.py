@@ -26,19 +26,28 @@ def _log_phi(z):
     return -0.5 * z * z - _C1
 
 
-_BRANCH = -25.0     # direct f64 eval is cancellation-safe above this
+# Below this the direct branch would need φ(z) < 1e-22.  A TPU emulates
+# float64 with float32's exponent range, where φ underflows below z ≈ -13
+# and the direct branch returns -inf with NaN gradients.
+_BRANCH = -10.0
+# (−1)ᵏ(2k+1)!! for k = 1..16: the asymptotic series of h(z)·z²/φ(z) − 1
+_ASYM = tuple((-1) ** k * math.prod(range(1, 2 * k + 2, 2))
+              for k in range(1, 17))
 
 
 def log_h(z: Array) -> Array:
     """log(φ(z) + z·Φ(z)) — the LogEI kernel, stable over all z.
 
     Branches (double-where guarded so gradients stay finite):
-      z > -25  : direct  log(φ(z) + zΦ(z)) — the cancellation error is
-                 ~eps·φ/h ≈ eps·z², still ≤1e-12 relative at z=-25 (f64);
-      z ≤ -25  : asymptotic from Φ(z) ~ φ(z)/(−z)·Σ(−1)ᵏ(2k−1)!!/z²ᵏ:
-                 log h = log φ − 2·log|z| + log1p(−3u + 15u² − 105u³),
-                 u = 1/z² (next term 945u⁴ ≤ 6e-9 at the branch point).
+      z > -10  : direct  log(φ(z) + zΦ(z)) — the cancellation amplifies
+                 erfc's rounding by φ/h ≈ z², to ~2e-12 relative near
+                 z=-10 (f64);
+      z ≤ -10  : asymptotic from Φ(z) ~ φ(z)/(−z)·Σ(−1)ᵏ(2k−1)!!/z²ᵏ:
+                 log h = log φ − 2·log|z| + log1p(Σₖ (−1)ᵏ(2k+1)!! uᵏ),
+                 u = 1/z², 16 terms (the next, 35!!·u¹⁷, is ≤1e-13 at
+                 the branch point).
     """
+    direct_side = z > _BRANCH
     z_safe_hi = jnp.maximum(z, _BRANCH)         # direct-branch input
     phi = jnp.exp(_log_phi(z_safe_hi))
     # erfc keeps Φ relatively accurate in the far tail (0.5·(1+erf) has
@@ -47,11 +56,17 @@ def log_h(z: Array) -> Array:
     direct_arg = jnp.maximum(phi + z_safe_hi * Phi, 1e-300)
     direct = jnp.log(direct_arg)
 
-    z_safe_lo = jnp.minimum(z, _BRANCH)         # asymptotic-branch input
+    # where, not minimum: at z == _BRANCH minimum splits the gradient
+    # between z and the constant, and the asymptotic branch taken there
+    # would get half of it
+    z_safe_lo = jnp.where(direct_side, _BRANCH, z)   # asymptotic input
     u = 1.0 / (z_safe_lo * z_safe_lo)
+    series = jnp.zeros_like(u)
+    for c in reversed(_ASYM):                   # Horner: u·(c₁ + u·(c₂ …))
+        series = u * (c + series)
     asym = (_log_phi(z_safe_lo) - 2.0 * jnp.log(-z_safe_lo)
-            + jnp.log1p(-3.0 * u + 15.0 * u * u - 105.0 * u * u * u))
-    return jnp.where(z > _BRANCH, direct, asym)
+            + jnp.log1p(series))
+    return jnp.where(direct_side, direct, asym)
 
 
 def log_ei(mean: Array, var: Array, best: Array) -> Array:

@@ -10,14 +10,10 @@ generator (``lbfgsb_worker``) that *yields* evaluation requests and
 *receives* results — cooperative multitasking as in the paper — and drive all
 workers round-by-round with one batched JAX evaluation per round.
 
-Task codes of scipy>=1.15's C ``setulb`` (verified empirically):
+Task codes of scipy's C ``setulb`` (scipy>=1.15, verified empirically):
   3 = FG   (evaluate objective+gradient at ``x``)
   1 = NEW_X (one QN iteration finished)
   2/4 = converged, 5 = user stop, anything else = error/stop.
-
-scipy<1.15 ships the original Fortran ``setulb`` whose task channel is a
-60-char string ('FG...', 'NEW_X', 'CONV...'); ``_SetulbDriver`` adapts both
-APIs to the integer codes above so the worker logic is version-agnostic.
 """
 from __future__ import annotations
 
@@ -34,13 +30,9 @@ _TASK_CONV = 2
 _TASK_STOP = 5
 _TASK_ERROR = 99
 
-# scipy>=1.15 rewrote setulb in C with integer task codes and no
-# iprint/csave; detect which ABI this interpreter has once at import.
-_HAS_C_SETULB = "iprint" not in (_lbfgsb.setulb.__doc__ or "iprint")
-
 
 class _SetulbDriver:
-    """Reverse-communication L-BFGS-B adapted to one integer task code.
+    """Reverse-communication L-BFGS-B reporting one integer task code.
 
     Owns the solver workspace for one restart; ``step()`` advances the
     underlying ``setulb`` once and returns one of the ``_TASK_*`` codes.
@@ -61,37 +53,17 @@ class _SetulbDriver:
         self.lsave = np.zeros(4, np.int32)
         self.isave = np.zeros(44, np.int32)
         self.dsave = np.zeros(29, np.float64)
-        if _HAS_C_SETULB:
-            self.task = np.zeros(2, np.int32)
-            self.ln_task = np.zeros(2, np.int32)
-        else:
-            self.task = np.zeros(1, "S60")
-            self.task[:] = b"START"
-            self.csave = np.zeros(1, "S60")
+        self.task = np.zeros(2, np.int32)
+        self.ln_task = np.zeros(2, np.int32)
 
     def step(self) -> int:
-        if _HAS_C_SETULB:
-            _lbfgsb.setulb(self.m, self.x, self.low, self.up, self.nbd,
-                           self.f, self.g, self.factr, self.pgtol, self.wa,
-                           self.iwa, self.task, self.lsave, self.isave,
-                           self.dsave, self.maxls, self.ln_task)
-            t = int(self.task[0])
-            if t in (_TASK_FG, _TASK_NEW_X, _TASK_CONV, 4, _TASK_STOP):
-                return _TASK_CONV if t == 4 else t
-            return _TASK_ERROR
         _lbfgsb.setulb(self.m, self.x, self.low, self.up, self.nbd,
                        self.f, self.g, self.factr, self.pgtol, self.wa,
-                       self.iwa, self.task, -1, self.csave, self.lsave,
-                       self.isave, self.dsave, self.maxls)
-        t = self.task.tobytes()
-        if t.startswith(b"FG"):
-            return _TASK_FG
-        if t.startswith(b"NEW_X"):
-            return _TASK_NEW_X
-        if t.startswith(b"CONV"):
-            return _TASK_CONV
-        if t.startswith(b"STOP"):
-            return _TASK_STOP
+                       self.iwa, self.task, self.lsave, self.isave,
+                       self.dsave, self.maxls, self.ln_task)
+        t = int(self.task[0])
+        if t in (_TASK_FG, _TASK_NEW_X, _TASK_CONV, 4, _TASK_STOP):
+            return _TASK_CONV if t == 4 else t
         return _TASK_ERROR
 
 EvalRequest = np.ndarray          # the point the worker wants evaluated

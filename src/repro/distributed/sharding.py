@@ -11,25 +11,13 @@ sharding head_dim instead (the decode-KV memory fix; see DESIGN.md §6).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Optional, Sequence, Tuple
+from typing import Any, ContextManager, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax._src import config as _jax_config
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-
-def get_abstract_mesh():
-    """Version-compat ``jax.sharding.get_abstract_mesh``.
-
-    jax<0.5 has no abstract-mesh registry; there the ambient mesh is the
-    ``with Mesh(...)`` context's physical mesh, which exposes the same
-    ``empty``/``axis_names``/``axis_sizes`` surface the callers use.
-    """
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    from jax._src import mesh as mesh_lib
-    return mesh_lib.thread_resources.env.physical_mesh
 
 # Per logical axis: ordered mesh-axis candidates (first match wins).
 AXIS_CANDIDATES = {
@@ -116,6 +104,17 @@ def fleet_shardings(mesh: Mesh, tree, axis: Optional[str] = None):
         lambda x: fleet_sharding(mesh, jnp.ndim(x), axis), tree)
 
 
+def gspmd_lowering() -> ContextManager:
+    """Lower the programs traced inside this context through GSPMD, not
+    Shardy.  The TPU compiler (libtpu 0.0.34) refuses a float64 Cholesky
+    partitioned over more than one device under Shardy ("A tuple parameter
+    that is being flattened shouldn't have frontend attributes").  JAX has
+    no public scoped switch for the partitioner, only the process-wide
+    ``jax_use_shardy_partitioner``; this is its thread-local context.  The
+    setting is part of the jit cache key."""
+    return _jax_config.use_shardy_partitioner(False)
+
+
 # ---------------------------------------------------------------------------
 # boxed parameters: value + logical axes travel together through init
 # ---------------------------------------------------------------------------
@@ -177,7 +176,7 @@ def param_shardings(tree, mesh: Mesh):
 
 def constrain(x: jax.Array, *axes: Optional[str]) -> jax.Array:
     """with_sharding_constraint via logical axes; no-op off-mesh."""
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty or not mesh.axis_names:
         return x
     shape = dict(zip(mesh.axis_names, mesh.axis_sizes))

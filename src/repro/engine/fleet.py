@@ -56,6 +56,7 @@ on per-device occupancy).
 """
 from __future__ import annotations
 
+import contextlib
 import time
 import zlib
 from dataclasses import dataclass
@@ -64,11 +65,11 @@ from typing import Callable, Dict, Hashable, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh
 
 from repro.core.lbfgsb import LbfgsbOptions, lbfgsb_minimize
-from repro.distributed.sharding import fleet_pspec, fleet_sharding
+from repro.distributed.sharding import (fleet_pspec, fleet_sharding,
+                                       gspmd_lowering)
 from repro.engine.ask import (_MSO_DEFAULT, SuggestInfo, incr_core,
                               refit_core, restart_points)
 from repro.engine.cache import CountingJit, retrace_report
@@ -285,6 +286,8 @@ class FleetEngine:
         self.on_quarantine: Optional[Callable] = None
         self._plan = EvalPlan.for_batch(cfg.n_restarts, cfg.dim)
         self._fit_opts = FIT_OPTS._replace(maxiter=cfg.gp_fit_maxiter)
+        # how the block programs lower; see the mesh branch below
+        self._partitioner = contextlib.nullcontext
         if mesh is None:
             self._ndev = 1
             self._slot_sharding = None
@@ -300,12 +303,12 @@ class FleetEngine:
             # one shard_map per block program: every operand/result leads
             # with the slot axis, so a single P(study) prefix spec splits
             # them all; each device runs the identical slot-local program
-            # (check_rep off: nothing is replicated, nothing is reduced)
+            # (check_vma off: nothing is replicated, nothing is reduced)
             spec = fleet_pspec(1, mesh.axis_names[0])
 
             def smap(fn):
-                return shard_map(fn, mesh=mesh, in_specs=spec,
-                                 out_specs=spec, check_rep=False)
+                return jax.shard_map(fn, mesh=mesh, in_specs=spec,
+                                     out_specs=spec, check_vma=False)
 
             full_impl, incr_impl, mso_impl = (
                 smap(self._full_impl), smap(self._incr_impl),
@@ -314,6 +317,10 @@ class FleetEngine:
             # operands (keys, masks, θ inits) land on the mesh here, so
             # cache identity never depends on live-device occupancy
             jit_kw = {"in_shardings": self._slot_sharding}
+            # the full refit's MAP fit holds a float64 Cholesky, which the
+            # TPU compiler refuses to partition under Shardy: every call
+            # of the mesh programs lowers through GSPMD instead
+            self._partitioner = gspmd_lowering
         # three programs per (bucket, slots) shape: full refit,
         # incremental refit, and the fleet MSO tail
         self._full_jit = CountingJit(full_impl, **jit_kw)
@@ -487,6 +494,25 @@ class FleetEngine:
             raise res
         return res
 
+    def gp_state(self, sid: Hashable) -> GPState:
+        """The study's current fitted GPState, sliced from its slot row
+        (tests/introspection; mirrors ``AskEngine.gp_state``)."""
+        st = self._studies[sid]
+        if st.block is None or not st.has_factor:
+            raise ValueError(f"study {sid!r} has no fitted state in a slot")
+        blk = st.block
+
+        def row(a):      # slot index as data: one program for every slot
+            return jax.lax.dynamic_index_in_dim(a, st.slot, keepdims=False)
+
+        valid = jnp.arange(blk.bucket) < st.n_fit
+        y_std, _, _ = standardize_masked(-row(blk.y), valid)
+        return GPState(x_train=row(blk.x), y_train=y_std,
+                       params=unpack_theta(row(blk.theta), self.cfg.dim),
+                       chol=row(blk.chol), alpha=row(blk.alpha),
+                       kernel=self.cfg.kernel,
+                       kinv=None if blk.kinv is None else row(blk.kinv))
+
     def study_theta(self, sid: Hashable) -> Optional[np.ndarray]:
         """The study's last fully-refit θ (for snapshots), or None if no
         full refit has committed yet."""
@@ -529,9 +555,10 @@ class FleetEngine:
         tr = obs.get()
         t0 = tr.now_us() if tr is not None else 0.0
         served = 0
-        for blk in self._blocks:
-            with obs.span("fleet.step_block", bucket=blk.bucket):
-                served += self._step_block(blk)
+        with self._partitioner():
+            for blk in self._blocks:
+                with obs.span("fleet.step_block", bucket=blk.bucket):
+                    served += self._step_block(blk)
         if tr is not None and served:
             tr.record_span("fleet.step", t0, tr.now_us() - t0,
                            served=served, n_blocks=len(self._blocks))

@@ -11,10 +11,12 @@ module routes that hot path:
                   through HBM; gradients route through a custom VJP;
 * ``"pallas_interpret"`` — same kernel in interpreter mode (CPU
                   validation / CI);
-* ``"auto"``    — pallas on TPU, xla elsewhere.
+* ``"auto"``    — xla, on every platform (see ``resolve_backend``).
 
-The fused path needs ``GPState.kinv`` (see ``gp.gpr.with_kinv``); states
-without it fall back to the Cholesky path regardless of backend.
+The fused path needs a Matérn-5/2 ``GPState`` carrying ``kinv`` (see
+``gp.gpr.with_kinv``).  A Pallas backend handed any other state raises:
+the kernel-or-Cholesky choice is made once, where the sampler resolves
+its backend, and never silently here.
 """
 from __future__ import annotations
 
@@ -36,7 +38,13 @@ def resolve_backend(backend: str = "auto") -> str:
     if backend not in BACKENDS:
         raise ValueError(f"backend must be one of {BACKENDS}")
     if backend == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
+        # The fused kernel computes in float32, and its variance
+        # amp - kᵀK⁻¹k cancels to rounding noise near the data once
+        # amp/noise reaches ~1e4: on a v5e it was off by 1e-2·amp where
+        # the true variance is 6e-6·amp, and LogEI then steers
+        # suggestions by that noise.  So ``auto`` never picks it; the
+        # kernel runs only when asked for by name.
+        return "xla"
     return backend
 
 
@@ -44,14 +52,18 @@ def posterior(gp: GPState, xb: Array, *, backend: str = "auto"
               ) -> Tuple[Array, Array]:
     """Batched posterior ((k,) mean, (k,) var) via the chosen backend."""
     backend = resolve_backend(backend)
-    if (backend.startswith("pallas") and gp.kernel == "matern52"
-            and gp.kinv is not None):
-        inv_ls = jnp.exp(-gp.params.log_lengthscale)
-        return matern52_posterior_op(
-            xb, gp.x_train, gp.alpha, gp.kinv, inv_ls,
-            gp.params.amplitude, backend="pallas",
-            interpret=(backend == "pallas_interpret"))
-    return predict(gp, xb)
+    if backend == "xla":
+        return predict(gp, xb)
+    if gp.kernel != "matern52" or gp.kinv is None:
+        raise ValueError(
+            f"posterior backend {backend!r} runs the fused Matérn-5/2 "
+            f"kernel and needs a GPState with kinv; got kernel="
+            f"{gp.kernel!r}, kinv={'set' if gp.kinv is not None else None}"
+            f" (use gp.gpr.with_kinv, or the 'xla' backend)")
+    inv_ls = jnp.exp(-gp.params.log_lengthscale)
+    return matern52_posterior_op(
+        xb, gp.x_train, gp.alpha, gp.kinv, inv_ls, gp.params.amplitude,
+        backend="pallas", interpret=(backend == "pallas_interpret"))
 
 
 # one acq function object per backend: the engine's jit caches key on

@@ -17,12 +17,24 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 SQRT5 = 2.2360679774997896
 
 TILE_M = 128
 TILE_N = 128
+
+# Constant block index for the index maps.  Grid indices are int32; a bare
+# Python 0 becomes an int64 constant under jax_enable_x64, and Mosaic then
+# refuses the kernel ("failed to legalize operation 'func.return'").
+_0 = np.int32(0)
+
+# The MXU's default pass rounds float32 operands to bfloat16; measured on a
+# v5e, that put the posterior variance off by up to 44x the amplitude.
+# HIGHEST keeps the float32 products every kernel here is written for
+# (and that interpret mode computes).
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _matern_kernel(a_ref, b_ref, asq_ref, bsq_ref, amp_ref, out_ref):
@@ -35,6 +47,7 @@ def _matern_kernel(a_ref, b_ref, asq_ref, bsq_ref, amp_ref, out_ref):
     b = b_ref[...]
     # MXU: (M, D) @ (D, N)
     ab = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                             precision=_F32,
                              preferred_element_type=jnp.float32)
     d2 = asq_ref[...] + bsq_ref[...].T - 2.0 * ab
     d2 = jnp.maximum(d2, 0.0)
@@ -74,11 +87,11 @@ def matern52_gram(x1: jax.Array, x2: jax.Array, inv_lengthscale: jax.Array,
         _matern_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((TILE_M, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((TILE_N, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((TILE_M, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((TILE_N, 1), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
+            pl.BlockSpec((TILE_M, d), lambda i, j: (i, _0)),
+            pl.BlockSpec((TILE_N, d), lambda i, j: (j, _0)),
+            pl.BlockSpec((TILE_M, 1), lambda i, j: (i, _0)),
+            pl.BlockSpec((TILE_N, 1), lambda i, j: (j, _0)),
+            pl.BlockSpec((1, 1), lambda i, j: (_0, _0)),
         ],
         out_specs=pl.BlockSpec((TILE_M, TILE_N), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
@@ -94,7 +107,12 @@ def matern52_gram(x1: jax.Array, x2: jax.Array, inv_lengthscale: jax.Array,
 
 VAR_FLOOR = 1e-16           # matches gpr.predict's variance clamp
 
-MAX_TRAIN = 2048            # K⁻¹ (N², f32) must fit VMEM alongside the tile
+# K⁻¹ (N², f32) must fit VMEM alongside the tile.  1280 is the largest
+# training size at which the v5e compile (x64 on, as every entry point
+# runs) accepts the kernel both alone and under a 16-slot vmap, as the
+# fleet calls it; at the next tile (1408) the vmapped kernel runs out of
+# VMEM.  tests/test_tpu_compile.py compiles the kernel at this bound.
+MAX_TRAIN = 1280
 
 
 def _posterior_kernel(a_ref, b_ref, asq_ref, bsq_ref, alpha_ref, kinv_ref,
@@ -114,6 +132,7 @@ def _posterior_kernel(a_ref, b_ref, asq_ref, bsq_ref, alpha_ref, kinv_ref,
     a = a_ref[...]
     b = b_ref[...]
     ab = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                             precision=_F32,
                              preferred_element_type=jnp.float32)
     d2 = asq_ref[...] + bsq_ref[...].T - 2.0 * ab
     d2 = jnp.maximum(d2, 0.0)
@@ -121,8 +140,10 @@ def _posterior_kernel(a_ref, b_ref, asq_ref, bsq_ref, alpha_ref, kinv_ref,
     k = amp_ref[0, 0] * (1.0 + SQRT5 * r + (5.0 / 3.0) * d2) * \
         jnp.exp(-SQRT5 * r)                                  # (TILE_Q, N)
 
-    mean_ref[...] = k @ alpha_ref[...]                        # (TILE_Q, 1)
+    mean_ref[...] = jnp.dot(k, alpha_ref[...], precision=_F32,
+                            preferred_element_type=jnp.float32)  # (TILE_Q, 1)
     t = jax.lax.dot_general(k, kinv_ref[...], (((1,), (0,)), ((), ())),
+                            precision=_F32,
                             preferred_element_type=jnp.float32)
     quad = jnp.sum(t * k, axis=-1, keepdims=True)             # (TILE_Q, 1)
     var_ref[...] = jnp.maximum(amp_ref[0, 0] - quad, VAR_FLOOR)
@@ -166,17 +187,17 @@ def matern52_posterior(xq: jax.Array, xt: jax.Array, alpha: jax.Array,
         _posterior_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((TILE_M, d), lambda i: (i, 0)),
-            pl.BlockSpec((N, d), lambda i: (0, 0)),
-            pl.BlockSpec((TILE_M, 1), lambda i: (i, 0)),
-            pl.BlockSpec((N, 1), lambda i: (0, 0)),
-            pl.BlockSpec((N, 1), lambda i: (0, 0)),
-            pl.BlockSpec((N, N), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
+            pl.BlockSpec((TILE_M, d), lambda i: (i, _0)),
+            pl.BlockSpec((N, d), lambda i: (_0, _0)),
+            pl.BlockSpec((TILE_M, 1), lambda i: (i, _0)),
+            pl.BlockSpec((N, 1), lambda i: (_0, _0)),
+            pl.BlockSpec((N, 1), lambda i: (_0, _0)),
+            pl.BlockSpec((N, N), lambda i: (_0, _0)),
+            pl.BlockSpec((1, 1), lambda i: (_0, _0)),
         ],
         out_specs=[
-            pl.BlockSpec((TILE_M, 1), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_M, 1), lambda i: (i, 0)),
+            pl.BlockSpec((TILE_M, 1), lambda i: (i, _0)),
+            pl.BlockSpec((TILE_M, 1), lambda i: (i, _0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Q, 1), jnp.float32),

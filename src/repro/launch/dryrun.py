@@ -30,8 +30,7 @@ import jax
 
 from repro.configs import ARCH_IDS, get_config
 from repro.launch.mesh import (HBM_BYTES, HBM_BW, ICI_BW_PER_LINK,
-                               PEAK_FLOPS_BF16, make_production_mesh,
-                               use_mesh)
+                               PEAK_FLOPS_BF16, make_production_mesh)
 from repro.launch.shapes import SHAPES, build_cell, cell_supported
 
 COLLECTIVE_OPS = ("all-reduce", "all-gather", "reduce-scatter",
@@ -108,7 +107,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     n_chips = mesh.devices.size
     t0 = time.perf_counter()
-    with use_mesh(mesh):
+    with jax.set_mesh(mesh):
         step, args, shards, out_shards, donate = build_cell(
             cfg, shape, mesh, grad_accum=grad_accum, opt_cfg=opt_cfg)
         jitted = jax.jit(step, in_shardings=shards,

@@ -3,32 +3,14 @@ touches jax device state (the dry-run pins the device count before any
 jax initialization)."""
 from __future__ import annotations
 
-import contextlib
-
 import jax
 
 
 def _make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the API has them
-    (jax>=0.5); plain mesh otherwise — semantics match for our usage."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def use_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh.
-
-    jax>=0.6 spells this ``jax.set_mesh``; on older releases the Mesh
-    object itself is the (physical-mesh) context manager.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return contextlib.nullcontext() if mesh is None else mesh
+    """``jax.make_mesh`` with Auto axis types (sharding propagation, as
+    the model code expects)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -24,8 +24,7 @@ from repro.ckpt.manager import CheckpointManager, install_sigterm_handler
 from repro.configs import get_config
 from repro.data.synth import DataConfig, synth_batch
 from repro.distributed.sharding import Boxed, is_boxed, param_pspecs
-from repro.launch.mesh import (make_production_mesh, make_smoke_mesh,
-                               use_mesh)
+from repro.launch.mesh import make_production_mesh, make_smoke_mesh
 from repro.launch.shapes import init_fn_for
 from repro.train.optim import OptimConfig, init_opt_state
 from repro.train.step import make_train_step
@@ -80,7 +79,7 @@ def main(argv=None):
         params = init_fn_for(cfg)(key, cfg)
         return params, init_opt_state(params, opt_cfg)
 
-    ctx = use_mesh(mesh) if mesh is not None else None
+    ctx = jax.set_mesh(mesh) if mesh is not None else None
     if ctx is not None:
         ctx.__enter__()
     try:

@@ -22,10 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
-from repro.distributed.sharding import (Boxed, box, constrain,
-                                         get_abstract_mesh)
+from repro.distributed.sharding import Boxed, box, constrain
 from repro.models.config import ModelConfig
 from repro.models.layers import _dense_init
 
@@ -123,7 +121,7 @@ def apply_moe(p: dict, cfg: ModelConfig, x: Array) -> Tuple[Array, Array]:
     k = cfg.experts_per_token
     E = cfg.n_experts
 
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     router_w = p["router"].value
     w_up, w_gate, w_down = (p["w_up"].value, p["w_gate"].value,
                             p["w_down"].value)
@@ -163,12 +161,12 @@ def apply_moe(p: dict, cfg: ModelConfig, x: Array) -> Tuple[Array, Array]:
 
     bspec = batch_axes if len(batch_axes) > 1 else \
         (batch_axes[0] if batch_axes else None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(bspec, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),
                   P("model", None, None)),
         out_specs=(P(bspec, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, router_w, w_up, w_gate, w_down)
     return y, aux

@@ -22,7 +22,7 @@ from typing import Any, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import Boxed, get_abstract_mesh, is_boxed
+from repro.distributed.sharding import Boxed, is_boxed
 
 Array = jax.Array
 
@@ -105,7 +105,7 @@ def apply_updates(params, grads, state: AdamState, cfg: OptimConfig
     # naive formulation makes GSPMD materialize f32 copies of the FULL
     # params/delta per leaf (≈3× param bytes of temps on the 34B/132B
     # train cells; see EXPERIMENTS.md §Perf).
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     use_zero = (mesh is not None and not mesh.empty
                 and "data" in getattr(mesh, "axis_names", ()))
     if use_zero:
@@ -187,7 +187,7 @@ def constrain_grads_zero1(grads):
     (ZeRO-2-style gradient sharding; the chameleon-34b fp32 grad
     accumulator does not fit HBM without this)."""
     from repro.distributed.sharding import pspec
-    mesh = get_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh is None or mesh.empty or "data" not in mesh.axis_names:
         return grads
     sizes = dict(zip(mesh.axis_names, mesh.axis_sizes))

@@ -2,7 +2,9 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
+from scipy.special import erfcx
 from scipy.stats import norm
 
 from repro.core.acquisition import ei, log_ei, log_h
@@ -28,6 +30,24 @@ def test_log_h_extreme_negative_finite():
     # asymptotic: log h(z) ≈ -z²/2 - log√(2π) - 2 log|z|
     approx = -z**2 / 2 - 0.5 * np.log(2 * np.pi) - 2 * np.log(-z)
     np.testing.assert_allclose(out, np.asarray(approx), rtol=1e-3)
+
+
+def test_log_h_matches_erfcx_reference_across_branch():
+    """Both branches of log_h, and the switch between them at z = -10,
+    against a float64 reference that does not cancel catastrophically:
+    h(z) = φ(z)·(1 − |z|·√(π/2)·erfcx(|z|/√2)), and d log h/dz = Φ/h."""
+    z = np.concatenate([np.linspace(-25.0, -9.0, 161),
+                        [-10.0 - 1e-7, -10.0 + 1e-7]])
+    e = erfcx(np.abs(z) / np.sqrt(2.0))
+    rest = 1.0 - np.abs(z) * np.sqrt(np.pi / 2.0) * e
+    ref = -0.5 * z * z - 0.5 * np.log(2.0 * np.pi) + np.log(rest)
+    grad_ref = np.sqrt(np.pi / 2.0) * e / rest
+    zj = jnp.asarray(z, jnp.float64)
+    # log h to 1e-10 absolute is h to 1e-10 relative
+    np.testing.assert_allclose(np.asarray(log_h(zj)), ref, rtol=0,
+                               atol=1e-10)
+    np.testing.assert_allclose(
+        np.asarray(jax.vmap(jax.grad(log_h))(zj)), grad_ref, rtol=1e-10)
 
 
 def test_log_h_gradient_finite_everywhere():

@@ -2,8 +2,10 @@
 shared ``run_sub`` conftest fixture) so the host-device-count flag never
 leaks into the rest of the suite (per the dry-run isolation requirement)."""
 import jax
+import jax.numpy as jnp
 
-from repro.distributed.sharding import pspec
+from repro.distributed.sharding import fleet_sharding, gspmd_lowering, pspec
+from repro.launch.mesh import make_fleet_mesh
 
 
 # ------------------------------------------------------------------ pspec
@@ -30,18 +32,31 @@ def test_pspec_single_device_mesh_noop():
         == jax.sharding.PartitionSpec(None, None)
 
 
+def test_gspmd_lowering_switches_off_shardy_inside_only():
+    """The scoped partitioner switch rests on a private JAX config state;
+    this fails when that state goes away or stops taking effect."""
+    f = jax.jit(lambda x: 2 * x,
+                in_shardings=fleet_sharding(make_fleet_mesh(1)))
+    was = jax.config.jax_use_shardy_partitioner
+    with gspmd_lowering():
+        assert jax.config.jax_use_shardy_partitioner is False
+        text = f.lower(jnp.ones(4)).as_text()
+        assert "sdy." not in text and "mhlo.sharding" in text
+    assert jax.config.jax_use_shardy_partitioner == was
+
+
 # -------------------------------------------------------------- lowering
 def test_train_step_lowers_on_smoke_mesh(run_sub):
     out = run_sub("""
         import jax
         from repro.configs import get_config
-        from repro.launch.mesh import make_smoke_mesh, use_mesh
+        from repro.launch.mesh import make_smoke_mesh
         from repro.launch.shapes import ShapeCell, build_cell
         cfg = get_config("llama3.2-3b").reduced().replace(
             dtype="float32", attn_chunk=16)
         mesh = make_smoke_mesh((2, 4), ("data", "model"))
         cell = ShapeCell("mini_train", "train", 32, 8)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             step, args, shards, outs, donate = build_cell(
                 cfg, cell, mesh, grad_accum=2)
             c = jax.jit(step, in_shardings=shards, out_shardings=outs,
@@ -55,13 +70,13 @@ def test_decode_lowers_on_smoke_mesh(run_sub):
     out = run_sub("""
         import jax
         from repro.configs import get_config
-        from repro.launch.mesh import make_smoke_mesh, use_mesh
+        from repro.launch.mesh import make_smoke_mesh
         from repro.launch.shapes import ShapeCell, build_cell
         cfg = get_config("recurrentgemma-9b").reduced().replace(
             dtype="float32", attn_chunk=16)
         mesh = make_smoke_mesh((2, 4), ("data", "model"))
         cell = ShapeCell("mini_decode", "decode", 64, 8)
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             step, args, shards, outs, donate = build_cell(cfg, cell, mesh)
             c = jax.jit(step, in_shardings=shards, out_shardings=outs,
                         donate_argnums=donate).lower(*args).compile()
@@ -82,9 +97,9 @@ def test_moe_sharded_matches_unsharded(run_sub):
         p = init_moe(key, cfg, jnp.float32)
         x = jax.random.normal(key, (4, 8, cfg.d_model), jnp.float32)
         y_ref, aux_ref = apply_moe(p, cfg, x)        # no mesh: local path
-        from repro.launch.mesh import make_smoke_mesh, use_mesh
+        from repro.launch.mesh import make_smoke_mesh
         mesh = make_smoke_mesh((2, 4), ("data", "model"))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             y_sh, aux_sh = jax.jit(lambda p, x: apply_moe(p, cfg, x))(p, x)
         err = float(jnp.max(jnp.abs(y_ref - y_sh)))
         print("ERR", err, float(aux_ref), float(aux_sh))
@@ -108,9 +123,9 @@ def test_sharded_ce_matches_unsharded(run_sub):
         batch = {"tokens": toks, "targets": tgts}
         ref = float(jax.jit(lambda p, b: lm.lm_loss(p, cfg, b))(params,
                                                                 batch))
-        from repro.launch.mesh import make_smoke_mesh, use_mesh
+        from repro.launch.mesh import make_smoke_mesh
         mesh = make_smoke_mesh((2, 4), ("data", "model"))
-        with use_mesh(mesh):
+        with jax.set_mesh(mesh):
             sh = float(jax.jit(lambda p, b: lm.lm_loss(p, cfg, b))(params,
                                                                    batch))
         print("LOSSES", ref, sh)
@@ -125,7 +140,7 @@ def test_elastic_restore_across_meshes(run_sub):
         import jax, jax.numpy as jnp, numpy as np, tempfile, os
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.ckpt.manager import CheckpointManager
-        from repro.launch.mesh import make_smoke_mesh, use_mesh
+        from repro.launch.mesh import make_smoke_mesh
         m1 = make_smoke_mesh((2, 4), ("data", "model"))
         m2 = make_smoke_mesh((4, 2), ("data", "model"))
         x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
@@ -154,7 +169,7 @@ def test_grad_compression_bf16_shrinks_accumulator(run_sub):
     out = run_sub("""
         import jax
         from repro.configs import get_config
-        from repro.launch.mesh import make_smoke_mesh, use_mesh
+        from repro.launch.mesh import make_smoke_mesh
         from repro.launch.shapes import ShapeCell, build_cell
         from repro.train.optim import OptimConfig
         cfg = get_config("llama3.2-3b").reduced().replace(
@@ -164,7 +179,7 @@ def test_grad_compression_bf16_shrinks_accumulator(run_sub):
         nbf16 = {}
         for mode in ("none", "bf16"):
             oc = OptimConfig(grad_compression=mode, shard_grads=False)
-            with use_mesh(mesh):
+            with jax.set_mesh(mesh):
                 step, args, shards, outs, donate = build_cell(
                     cfg, cell, mesh, opt_cfg=oc, grad_accum=4)
                 comp = jax.jit(step, in_shardings=shards,

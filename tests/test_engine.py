@@ -8,8 +8,11 @@ import pytest
 from repro.core import coroutine as co
 from repro.core.acquisition import logei_acq, qlogei_acq, qlogei_state
 from repro.core.mso import MsoOptions, maximize_acqf
-from repro.engine import EvalEngine, EvalPlan, bucket_ladder, fused_logei_acq
-from repro.gp.gpr import fit_gram, pad_gp, with_kinv
+from repro.bo.sampler import FleetSampler, GPSampler
+from repro.bo.space import BoxSpace
+from repro.engine import (EvalEngine, EvalPlan, bucket_ladder, fused_logei_acq,
+                          posterior, resolve_backend)
+from repro.gp.gpr import GPState, fit_gram, pad_gp, with_kinv
 from repro.gp.kernels import init_params
 from repro.kernels.matern.ops import matern52_posterior_op
 from repro.kernels.matern.ref import matern52_posterior_ref
@@ -280,6 +283,49 @@ def test_fused_backend_through_mso(gp50):
                             0.0, 1.0, acq_state=state, strategy="dbe",
                             options=opts)
     assert abs(r_fused.best_acq - r_xla.best_acq) < 1e-2
+
+
+@pytest.mark.parametrize("backend", ["pallas", "pallas_interpret"])
+@pytest.mark.parametrize("kernel,drop_kinv", [("matern52", True),
+                                              ("rbf", False)],
+                         ids=["no-kinv", "rbf"])
+def test_pallas_posterior_refuses_unfusable_state(gp50, backend, kernel,
+                                                  drop_kinv):
+    """A Pallas backend never falls back to the Cholesky predict: a state
+    the fused kernel cannot serve raises instead."""
+    gp, _ = gp50
+    bad = GPState(x_train=gp.x_train, y_train=gp.y_train, params=gp.params,
+                  chol=gp.chol, alpha=gp.alpha, kernel=kernel,
+                  kinv=None if drop_kinv else gp.kinv)
+    X = jnp.asarray(np.random.default_rng(12).uniform(0, 1, (5, 4)))
+    with pytest.raises(ValueError, match="kinv"):
+        posterior(bad, X, backend=backend)
+
+
+def test_auto_backend_resolves_to_xla_off_tpu():
+    """Off the TPU, ``auto`` is the Cholesky path, chosen where the
+    samplers resolve their backend."""
+    assert jax.default_backend() != "tpu"
+    assert resolve_backend("auto") == "xla"
+    space = BoxSpace.cube(3, 0.0, 1.0)
+    s = GPSampler(space)
+    assert s.posterior_backend == "xla" and s._acq_fn is logei_acq
+    fs = FleetSampler(space, n_studies=2, slots=2)
+    assert fs.fleet.cfg.backend == "xla"
+    assert fs.engine.acq_fn is logei_acq
+
+
+@pytest.mark.parametrize("x64", [True, False], ids=["x64", "x32"])
+def test_auto_backend_on_tpu_is_xla(monkeypatch, x64):
+    """On a TPU too, ``auto`` is the Cholesky path whatever the state's
+    dtype: the float32 kernel runs only when asked for by name."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    was = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", x64)
+    try:
+        assert resolve_backend("auto") == "xla"
+    finally:
+        jax.config.update("jax_enable_x64", was)
 
 
 def test_pad_gp_extends_kinv(gp50):

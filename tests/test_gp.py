@@ -3,7 +3,8 @@ hyperparameter fit sanity, property-based invariants."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.gp.fit import fit_gp, standardize
 from repro.gp.gpr import (GPState, fit_gram, log_marginal_likelihood,

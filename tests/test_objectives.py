@@ -1,7 +1,8 @@
 """BBOB objective sanity + search-space tests."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.bo.objectives import OBJECTIVES, make_objective
 from repro.bo.space import BoxSpace

@@ -8,7 +8,8 @@ sleeps, no wall-clock margins."""
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 from faults import FaultInjector, VirtualClock
 from repro.bo.journal import InjectedCrash, StudyJournal
 from repro.bo.sampler import FleetSampler
