@@ -1,0 +1,14 @@
+"""GP refit programs (``fleet.program.full`` and ``fleet.program.incr``):
+their spans, which run to ``block_until_ready``, summed per step that
+served asks, over the steps that start after the profiler's stop has
+returned."""
+from bench.tracing import spans_named, total
+
+
+def read(run):
+    spans, n = run.clean_steps()
+    progs = (spans_named(spans, "fleet.program.full")
+             + spans_named(spans, "fleet.program.incr"))
+    if not progs or not n:
+        return None
+    return 1e-3 * total(progs) / n
