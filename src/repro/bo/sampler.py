@@ -662,24 +662,28 @@ class FleetSampler:
         studies = list(studies)
         tr = obs.get()
         t0 = tr.now_us() if tr is not None else 0.0
-        for i in studies:
-            s = self.samplers[i]
-            if s._fleet is not None:
-                s.prefetch_suggest()
+        # observation sync (eager slot-row updates) and suggest requests
+        with obs.span("fleet.prefetch", n=len(studies)):
+            for i in studies:
+                s = self.samplers[i]
+                if s._fleet is not None:
+                    s.prefetch_suggest()
         self.fleet.step()
         out: List = []
-        for i in studies:
-            s = self.samplers[i]
-            n_done = sum(t.state == "complete" for t in s.trials)
-            startup = n_done < s.n_startup
-            try:
-                t = s.ask()
-            except Exception as e:       # noqa: BLE001 — study isolation
-                out.append(e)
-                continue
-            self._append({"op": "ask", "study": i, "trial": t.trial_id,
-                          "x": t.x.tolist(), "startup": startup})
-            out.append(t)
+        # collect each study's suggestion and journal its ask
+        with obs.span("fleet.deliver", n=len(studies)):
+            for i in studies:
+                s = self.samplers[i]
+                n_done = sum(t.state == "complete" for t in s.trials)
+                startup = n_done < s.n_startup
+                try:
+                    t = s.ask()
+                except Exception as e:   # noqa: BLE001 — study isolation
+                    out.append(e)
+                    continue
+                self._append({"op": "ask", "study": i, "trial": t.trial_id,
+                              "x": t.x.tolist(), "startup": startup})
+                out.append(t)
         if tr is not None:
             tr.record_span("fleet.ask_batch", t0, tr.now_us() - t0,
                            n=len(studies))

@@ -69,6 +69,7 @@ class LbfgsbState(NamedTuple):
     status: Array       # (B,) int32 RUNNING / CONV_*
     n_evals: Array      # (B,) int32 per-restart *active* objective evals
     rounds: Array       # () int32 number of batched evaluation rounds
+    done_round: Array   # (B,) int32 ``rounds`` when the restart stopped
 
 
 class LbfgsbResult(NamedTuple):
@@ -79,6 +80,9 @@ class LbfgsbResult(NamedTuple):
     status: Array       # (B,)
     n_evals: Array      # (B,)
     rounds: Array       # () total batched rounds (line-search rounds incl.)
+    done_round: Array   # (B,) rounds at the end of the restart's last
+                        # iteration (1 if converged at the start point);
+                        # rounds - done_round is how long it sat frozen
     state: LbfgsbState  # final full state (history introspection)
 
 
@@ -180,6 +184,7 @@ def _init_state(fun_batched, x0, lower, upper, opts: LbfgsbOptions
         status=jnp.full((B,), RUNNING, jnp.int32),
         n_evals=jnp.ones((B,), jnp.int32),
         rounds=jnp.asarray(1, jnp.int32),
+        done_round=jnp.zeros((B,), jnp.int32),
     )
 
 
@@ -188,7 +193,9 @@ def _check_initial_convergence(state: LbfgsbState, lower, upper,
     pg = projected_grad(state.x, state.g, lower, upper)
     done = jnp.max(jnp.abs(pg), axis=-1) <= opts.pgtol
     status = jnp.where(done, CONV_PGTOL, state.status)
-    return state._replace(status=status.astype(jnp.int32))
+    return state._replace(status=status.astype(jnp.int32),
+                          done_round=jnp.where(done, state.rounds,
+                                               state.done_round))
 
 
 def _step(fun_batched, lower, upper, opts: LbfgsbOptions,
@@ -303,6 +310,7 @@ def _step(fun_batched, lower, upper, opts: LbfgsbOptions,
                        CONV_MAXITER, status)
 
     keep = running[:, None]
+    rounds = state.rounds + ls.rounds
     return LbfgsbState(
         x=jnp.where(keep, x_new, state.x),
         f=jnp.where(running, f_new, state.f),
@@ -311,7 +319,9 @@ def _step(fun_batched, lower, upper, opts: LbfgsbOptions,
         start=start, length=length, gamma=gamma,
         k=k_new, status=status.astype(jnp.int32),
         n_evals=state.n_evals + ls.n_evals,
-        rounds=state.rounds + ls.rounds,
+        rounds=rounds,
+        done_round=jnp.where(running & (status != RUNNING), rounds,
+                             state.done_round),
     )
 
 
@@ -326,7 +336,8 @@ def _minimize_2d(fun_batched, x0, lower, upper,
         lambda s: jnp.any(s.status == RUNNING), step, state)
     return LbfgsbResult(x=state.x, f=state.f, g=state.g, k=state.k,
                         status=state.status, n_evals=state.n_evals,
-                        rounds=state.rounds, state=state)
+                        rounds=state.rounds, done_round=state.done_round,
+                        state=state)
 
 
 def lbfgsb_minimize(

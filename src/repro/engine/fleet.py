@@ -67,7 +67,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from repro.core.lbfgsb import LbfgsbOptions, lbfgsb_minimize
+from repro.core.lbfgsb import (CONV_LS_FAIL, CONV_MAXITER, LbfgsbOptions,
+                               lbfgsb_minimize)
 from repro.distributed.sharding import (fleet_pspec, fleet_sharding,
                                        gspmd_lowering)
 from repro.engine.ask import (_MSO_DEFAULT, SuggestInfo, incr_core,
@@ -359,6 +360,18 @@ class FleetEngine:
         self.n_retries = 0               # quarantine retry refit launches
         self.n_retry_backoffs = 0        # backoff sleeps taken
         self.backoff_total_s = 0.0       # total backoff charged (seconds)
+        # lockstep counters, summed over MSO solves (one per block step):
+        # n_rounds = solves + iters + ls_rounds; a requesting study's
+        # wait rounds are those its restarts sat frozen after the last
+        # of them stopped, while other lanes kept the loop running
+        self.n_mso_solves = 0
+        self.n_mso_iters = 0             # outer iterations (max k)
+        self.n_mso_ls_rounds = 0         # rounds past one per iteration
+        self.n_mso_study_rounds = 0      # rounds x requesting studies
+        self.n_mso_study_wait_rounds = 0
+        self.n_mso_capped_lanes = 0      # requesting lanes at maxiter or
+                                         # a failed line search
+        self.n_eager_updates = 0         # block scatters outside programs
 
     def _journal(self, record: dict) -> None:
         if self.journal is not None:
@@ -432,9 +445,8 @@ class FleetEngine:
             self._evict(st)
         else:
             i = st.n - 1
-            blk.x = blk._pin(blk.x.at[st.slot, i].set(
-                jnp.asarray(x_unit, blk.x.dtype)))
-            blk.y = blk._pin(blk.y.at[st.slot, i].set(float(y)))
+            self._set(blk, "x", (st.slot, i), x_unit)
+            self._set(blk, "y", (st.slot, i), y)
 
     def request_suggest(self, sid: Hashable, key: Optional[Array] = None,
                         fit_seed: Optional[int] = None) -> None:
@@ -557,8 +569,9 @@ class FleetEngine:
         served = 0
         with self._partitioner():
             for blk in self._blocks:
-                with obs.span("fleet.step_block", bucket=blk.bucket):
-                    served += self._step_block(blk)
+                with obs.span("fleet.step_block",
+                              bucket=blk.bucket) as args:
+                    served += self._step_block(blk, args)
         if tr is not None and served:
             tr.record_span("fleet.step", t0, tr.now_us() - t0,
                            served=served, n_blocks=len(self._blocks))
@@ -586,6 +599,13 @@ class FleetEngine:
             "n_retries": self.n_retries,
             "n_retry_backoffs": self.n_retry_backoffs,
             "backoff_total_s": round(self.backoff_total_s, 6),
+            "n_mso_solves": self.n_mso_solves,
+            "n_mso_iters": self.n_mso_iters,
+            "n_mso_ls_rounds": self.n_mso_ls_rounds,
+            "n_mso_study_rounds": self.n_mso_study_rounds,
+            "n_mso_study_wait_rounds": self.n_mso_study_wait_rounds,
+            "n_mso_capped_lanes": self.n_mso_capped_lanes,
+            "n_eager_updates": self.n_eager_updates,
             "n_devices": self._ndev,
             "slots_per_device": self._device_occupancy(),
             "queue_depth": len(self._queue),
@@ -693,13 +713,10 @@ class FleetEngine:
         x_row[:n] = np.stack(st.xs)
         y_row = np.zeros((blk.bucket,))
         y_row[:n] = st.ys
-        blk.x = blk._pin(blk.x.at[slot].set(jnp.asarray(x_row,
-                                                        blk.x.dtype)))
-        blk.y = blk._pin(blk.y.at[slot].set(jnp.asarray(y_row,
-                                                        blk.y.dtype)))
+        self._set(blk, "x", slot, x_row)
+        self._set(blk, "y", slot, y_row)
         if st.theta_host is not None:
-            blk.theta = blk._pin(blk.theta.at[slot].set(
-                jnp.asarray(st.theta_host, blk.theta.dtype)))
+            self._set(blk, "theta", slot, st.theta_host)
         self._journal({"op": "admit", "sid": st.sid,
                        "bucket": blk.bucket, "slot": slot, "n": n})
         obs.instant("fleet.admit", sid=str(st.sid), bucket=blk.bucket,
@@ -713,6 +730,15 @@ class FleetEngine:
                 self.n_migrations_cross += 1
             st.from_device = None
 
+    def _set(self, blk: _Block, name: str, idx, value) -> None:
+        """One eager device update of a block buffer, outside the three
+        block programs (observe, install, evict, quarantine): counted in
+        ``n_eager_updates``."""
+        a = getattr(blk, name)
+        setattr(blk, name, blk._pin(a.at[idx].set(jnp.asarray(value,
+                                                                a.dtype))))
+        self.n_eager_updates += 1
+
     def _clear_slot(self, st: _Study) -> None:
         """Free the study's slot: save θ for a warm start, reset the row
         to the benign idle pattern (the _FAR invariant holds for every
@@ -720,17 +746,14 @@ class FleetEngine:
         blk, s = st.block, st.slot
         if st.has_theta:
             st.theta_host = np.asarray(blk.theta[s])
-        dt = blk.x.dtype
-        blk.x = blk._pin(blk.x.at[s].set(jnp.asarray(blk.idle_x, dt)))
-        blk.y = blk._pin(blk.y.at[s].set(jnp.zeros((blk.bucket,), dt)))
-        blk.theta = blk._pin(blk.theta.at[s].set(
-            jnp.asarray(blk.theta0, dt)))
-        eye = jnp.eye(blk.bucket, dtype=dt)
-        blk.chol = blk._pin(blk.chol.at[s].set(eye))
-        blk.alpha = blk._pin(blk.alpha.at[s].set(
-            jnp.zeros((blk.bucket,), dt)))
+        eye = np.eye(blk.bucket)
+        self._set(blk, "x", s, blk.idle_x)
+        self._set(blk, "y", s, np.zeros((blk.bucket,)))
+        self._set(blk, "theta", s, blk.theta0)
+        self._set(blk, "chol", s, eye)
+        self._set(blk, "alpha", s, np.zeros((blk.bucket,)))
         if blk.kinv is not None:
-            blk.kinv = blk._pin(blk.kinv.at[s].set(eye))
+            self._set(blk, "kinv", s, eye)
         blk.studies[s] = None
         st.block, st.slot = None, -1
         st.from_device = self._slot_device(s)
@@ -772,10 +795,8 @@ class FleetEngine:
         st.tags.pop()
         blk, s = st.block, st.slot
         if blk is not None:
-            dt = blk.x.dtype
-            blk.x = blk._pin(blk.x.at[s, k].set(
-                jnp.asarray(blk.idle_x[k], dt)))
-            blk.y = blk._pin(blk.y.at[s, k].set(jnp.asarray(0.0, dt)))
+            self._set(blk, "x", (s, k), blk.idle_x[k])
+            self._set(blk, "y", (s, k), 0.0)
         st.n_fit = min(st.n_fit, st.n)
         st.has_factor = False        # the factor summed the dropped row
         if self.on_quarantine is not None:
@@ -784,7 +805,10 @@ class FleetEngine:
             self._park(st, f"only {st.n} clean observations "
                        f"after quarantine")
 
-    def _step_block(self, blk: _Block) -> int:
+    def _step_block(self, blk: _Block, solve: dict) -> int:
+        """Refit and solve the block's requesting studies; adds the MSO
+        solve's lockstep numbers to ``solve`` (the step_block span's
+        args)."""
         cfg = self.cfg
         req = [(s, st) for s, st in enumerate(blk.studies)
                if st is not None and st.pending is not None]
@@ -946,7 +970,7 @@ class FleetEngine:
             jnp.asarray(keys), blk.x, blk.y, nv, blk.theta, blk.chol,
             blk.alpha, blk.kinv)
         bx = np.asarray(best_x)                     # ONE (S, D) transfer
-        k_arr, ev_arr, rounds, bacq = stats
+        k_arr, ev_arr, rounds, bacq, status, done_round = stats
         # rounds is per-slot: each slot reports its own device's lockstep
         # round count (devices loop independently on a mesh; on one
         # device every slot sees the same shared count)
@@ -967,7 +991,36 @@ class FleetEngine:
             ev_live[s] = np.asarray(ev_arr[s])
         self.engine.record_lockstep_economy(S * cfg.n_restarts,
                                             int(rounds.max()), ev_live)
+        solve.update(self._count_lockstep(
+            [s for s, _ in req], rounds, np.asarray(k_arr),
+            np.asarray(status), np.asarray(done_round)))
         return len(req)
+
+    def _count_lockstep(self, req_slots: List[int], rounds: np.ndarray,
+                        k: np.ndarray, status: np.ndarray,
+                        done_round: np.ndarray) -> Dict[str, int]:
+        """Fold one MSO solve into the lockstep counters.  ``rounds`` is
+        per slot, ``k``/``status``/``done_round`` per slot and restart.
+        On a mesh each device loops on its own; the solve's rounds, as in
+        ``n_rounds``, are those of the device that looped longest, and a
+        study waits on its own device's loop."""
+        lanes = self.cfg.slots                   # slots per device
+        d = int(np.argmax(rounds)) // lanes
+        n_rounds = int(rounds.max())
+        iters = int(k[d * lanes:(d + 1) * lanes].max())
+        ls_rounds = n_rounds - 1 - iters
+        r = rounds[req_slots]
+        wait = int(np.sum(r - done_round[req_slots].max(axis=1)))
+        capped = int(np.isin(status[req_slots],
+                             (CONV_MAXITER, CONV_LS_FAIL)).sum())
+        self.n_mso_solves += 1
+        self.n_mso_iters += iters
+        self.n_mso_ls_rounds += ls_rounds
+        self.n_mso_study_rounds += int(r.sum())
+        self.n_mso_study_wait_rounds += wait
+        self.n_mso_capped_lanes += capped
+        return {"rounds": n_rounds, "iters": iters, "ls_rounds": ls_rounds,
+                "wait_rounds": wait, "capped": capped}
 
     # ------------------------------------------------------- device side
     def _full_impl(self, x, y, n_valid, thetas, tlo, tup, do_full,
@@ -1055,4 +1108,5 @@ class FleetEngine:
         # (independent) round count, and every output leads with the
         # slot axis so one P(study) out-spec covers the whole pytree
         rounds = jnp.full((x.shape[0],), res.rounds)
-        return best_x, (res.k, res.n_evals, rounds, best_acq)
+        return best_x, (res.k, res.n_evals, rounds, best_acq, res.status,
+                        res.done_round)

@@ -116,16 +116,18 @@ def get() -> Optional[Tracer]:
 
 
 @contextmanager
-def span(name: str, **attrs: Any) -> Iterator[None]:
+def span(name: str, **attrs: Any) -> Iterator[Dict[str, Any]]:
     """Time a host-side region as a complete ("X") event; no-op when
-    tracing is disabled.  Attributes land in the event's ``args``."""
+    tracing is disabled.  Attributes land in the event's ``args``; the
+    body gets the attribute dict and may add what it learns by the end
+    (a solve's round count, say)."""
     tr = _TRACER
     if tr is None:
-        yield
+        yield attrs
         return
     t0 = tr.now_us()
     try:
-        yield
+        yield attrs
     finally:
         tr.record_span(name, t0, tr.now_us() - t0, **attrs)
 

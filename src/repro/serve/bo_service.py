@@ -143,7 +143,7 @@ class _Request:
 
     __slots__ = ("rid", "tenant", "study", "submit_t", "deadline", "state",
                  "result", "error", "attempts", "not_before", "done_t",
-                 "event")
+                 "event", "submit_us", "dispatch_us")
 
     def __init__(self, rid: int, tenant: str, study: int, submit_t: float,
                  deadline: Optional[float]):
@@ -159,16 +159,27 @@ class _Request:
         self.not_before: Optional[float] = None   # backoff eligibility
         self.done_t: Optional[float] = None
         self.event: Optional[asyncio.Event] = None   # async waiter, if any
+        # tracer clock (tracing on only): at submit and at last dispatch
+        self.submit_us: Optional[float] = None
+        self.dispatch_us: Optional[float] = None
 
     @property
     def done(self) -> bool:
         return self.state in ("done", "shed", "failed")
 
-    def _wake(self) -> None:
-        """Wake the async waiter (if one attached) after a terminal
-        state transition.  Every code path that sets a terminal state
-        must call this, or an :meth:`BOService.ask` coroutine waits
-        forever."""
+    def _finish(self) -> None:
+        """Close the request after a terminal state transition: record
+        its ``svc.request`` span, submit to now (when it was stamped at
+        submit), and wake the async waiter (if one attached).  Every code
+        path that sets a terminal state must call this, or an
+        :meth:`BOService.ask` coroutine waits forever."""
+        tr = obs.get()
+        if tr is not None and self.submit_us is not None:
+            tr.record_span("svc.request", self.submit_us,
+                           tr.now_us() - self.submit_us, rid=self.rid,
+                           tenant=self.tenant, study=self.study,
+                           attempts=self.attempts, state=self.state,
+                           dispatch_us=self.dispatch_us)
         if self.event is not None:
             self.event.set()
 
@@ -350,6 +361,9 @@ class BOService:
                        "study": study, "t": now, "deadline": dl})
         self._req_seq += 1
         req = _Request(rid, tenant, study, now, dl)
+        tr = obs.get()
+        if tr is not None:
+            req.submit_us = tr.now_us()
         t.queue.append(req)
         t.n_submitted += 1
         return req
@@ -453,7 +467,7 @@ class BOService:
         t.n_deadline_miss += 1
         self.n_shed += 1
         self.n_deadline_miss += 1
-        req._wake()
+        req._finish()
 
     # ------------------------------------------------------ overload ladder
     def _update_rung(self, now: float) -> None:
@@ -540,7 +554,7 @@ class BOService:
             req.done_t = now
             t.n_shed += 1
             self.n_shed += 1
-            req._wake()
+            req._finish()
         for study in t.cfg.studies:
             s = self.fs.samplers[study]
             if s._fleet is not None:
@@ -581,11 +595,14 @@ class BOService:
         """Journal dispatches, run ONE batched fleet trial boundary for
         the scheduled studies, resolve results/retries/late sheds."""
         fi = self.fs.fault_injector
+        tr = obs.get()
         live: List[_Request] = []
         for req in batch:
             self._journal({"op": "svc_dispatch", "req": req.rid,
                            "study": req.study})
             req.attempts += 1
+            if tr is not None:
+                req.dispatch_us = tr.now_us()
             if fi is not None and hasattr(fi, "ask_ok") \
                     and not fi.ask_ok(req.study):
                 self._retry(req, RuntimeError(
@@ -619,7 +636,7 @@ class BOService:
             t.latencies.append(lat)
             self.n_completed += 1
             served += 1
-            req._wake()
+            req._finish()
         return served
 
     def _retry(self, req: _Request, err: BaseException) -> None:
@@ -639,7 +656,7 @@ class BOService:
             req.done_t = self._now()
             t.n_shed += 1
             self.n_shed += 1
-            req._wake()
+            req._finish()
             return
         delay = min(self.backoff_base * (2.0 ** (req.attempts - 1)),
                     self.backoff_cap)
@@ -682,7 +699,7 @@ class BOService:
                     f"request {req.rid} interrupted by drain (journaled; "
                     f"recovery restores it)")
                 req.done_t = now
-                req._wake()
+                req._finish()
             t.queue.clear()
         for req in self._delayed:
             req.state = "shed"
@@ -690,7 +707,7 @@ class BOService:
                 f"request {req.rid} interrupted by drain (journaled; "
                 f"recovery restores it)")
             req.done_t = now
-            req._wake()
+            req._finish()
         self._delayed = []
         return self.fs.drain()
 
@@ -863,7 +880,7 @@ class BOService:
         if not req.done:
             # event-wait, not a sleep(0) poll loop: the waiting client
             # coroutine parks until the server task resolves the
-            # request (every terminal transition calls req._wake()),
+            # request (every terminal transition calls req._finish()),
             # so idle waiters cost the event loop nothing
             req.event = asyncio.Event()
             if req.done:     # resolved between submit and attach

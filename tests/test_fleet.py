@@ -362,3 +362,97 @@ def test_lbfgsb_leading_batch_matches_2d():
                                       np.asarray(ref.f))
         np.testing.assert_array_equal(np.asarray(res.status[s]),
                                       np.asarray(ref.status))
+
+
+# ------------------------------------------------------ lockstep counters
+def _counted_fleet(n_studies=3, maxiter=40):
+    from repro.core.acquisition import logei_acq
+    cfg = FleetConfig(dim=2, n_restarts=4, slots=4, pad_bucket=8,
+                      refit_interval=2, gp_fit_restarts=2,
+                      mso=LbfgsbOptions(m=10, maxiter=maxiter, pgtol=1e-2,
+                                        ftol=0.0, maxls=25))
+    fleet = FleetEngine(EvalEngine(logei_acq), cfg)
+    rng = np.random.default_rng(11)
+    for sid in range(n_studies):
+        fleet.add_study(sid)
+        for x in rng.uniform(0, 1, (4, 2)):
+            fleet.observe(sid, x, _sphere(x) + 0.3 * sid)
+    return fleet
+
+
+def _spy_lockstep(fleet):
+    """Record the per-study numbers each solve's counters were folded
+    from: {slot: (solve rounds, last done_round of its restarts)}."""
+    seen = []
+    orig = fleet._count_lockstep
+
+    def spy(req_slots, rounds, k, status, done_round):
+        seen.append({s: (int(rounds[s]), int(done_round[s].max()))
+                     for s in req_slots})
+        return orig(req_slots, rounds, k, status, done_round)
+    fleet._count_lockstep = spy
+    return seen
+
+
+def test_fleet_lockstep_counters_add_up():
+    """Every lockstep round is the start round, an outer iteration or a
+    line-search retry; each requesting study waits the rounds after its
+    last restart stopped, so the slowest study waits none."""
+    fleet = _counted_fleet()
+    seen = _spy_lockstep(fleet)
+    before = {**fleet.engine.stats_snapshot(), **fleet.stats_snapshot()}
+    for trial in range(3):                      # full + incremental steps
+        for sid in range(3):
+            fleet.request_suggest(sid)
+        assert fleet.step() == 3
+        for sid in range(3):
+            x, _ = fleet.pop_result(sid)
+            fleet.observe(sid, np.clip(x, 0, 1), _sphere(np.clip(x, 0, 1)))
+    after = {**fleet.engine.stats_snapshot(), **fleet.stats_snapshot()}
+    d = {k: after[k] - before[k] for k in (
+        "n_rounds", "n_mso_solves", "n_mso_iters", "n_mso_ls_rounds",
+        "n_mso_study_rounds", "n_mso_study_wait_rounds",
+        "n_mso_capped_lanes", "n_steps")}
+    assert d["n_mso_solves"] == d["n_steps"] == 3
+    assert d["n_rounds"] == (d["n_mso_solves"] + d["n_mso_iters"]
+                             + d["n_mso_ls_rounds"])
+    assert d["n_mso_iters"] > 0 and d["n_mso_ls_rounds"] >= 0
+    assert d["n_mso_study_rounds"] == 3 * d["n_rounds"]
+    assert 0 <= d["n_mso_capped_lanes"] <= 3 * 3 * fleet.cfg.n_restarts
+    waits = [{s: r - last for s, (r, last) in solve.items()}
+             for solve in seen]
+    assert d["n_mso_study_wait_rounds"] == sum(sum(w.values())
+                                               for w in waits)
+    for w in waits:
+        assert min(w.values()) == 0             # the slowest study
+        assert max(w.values()) > 0              # one stopped early
+
+
+def test_fleet_capped_lanes_counted():
+    """Restarts stopped by maxiter are counted, requesting lanes only."""
+    fleet = _counted_fleet(n_studies=2, maxiter=1)
+    fleet.request_suggest(0)
+    fleet.step()
+    snap = fleet.stats_snapshot()
+    assert 0 < snap["n_mso_capped_lanes"] <= fleet.cfg.n_restarts
+    assert snap["n_mso_iters"] == 1
+
+
+def test_fleet_eager_updates_two_per_observed_trial():
+    """observe() writes a slot's x and y rows: two eager device updates
+    per trial once the study holds a slot; admission and eviction count
+    theirs too."""
+    fleet = _counted_fleet(n_studies=2)
+    assert fleet.stats_snapshot()["n_eager_updates"] == 0  # queued only
+    fleet.request_suggest(0)
+    fleet.step()
+    n0 = fleet.stats_snapshot()["n_eager_updates"]
+    assert n0 == 2 * 2                     # two installs: x and y rows
+    x, _ = fleet.pop_result(0)
+    for k in range(4):                     # n 4 -> 8, bucket 8 holds
+        fleet.observe(0, x, 1.0 + k)
+        assert fleet.stats_snapshot()["n_eager_updates"] == n0 + 2 * (k + 1)
+    n1 = fleet.stats_snapshot()["n_eager_updates"]
+    fleet.observe(0, x, 5.0)               # n 8 -> 9: leaves the bucket
+    # eviction resets x, y, theta, chol and alpha of the slot
+    assert fleet.stats_snapshot()["n_eager_updates"] == n1 + 5

@@ -7,9 +7,9 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 from scipy.optimize import minimize
 
-from repro.core.lbfgsb import (CONV_PGTOL, LbfgsbOptions, bfgs_minimize,
-                               inv_hessian_dense, lbfgsb_minimize,
-                               make_batched_value_and_grad)
+from repro.core.lbfgsb import (CONV_MAXITER, CONV_PGTOL, LbfgsbOptions,
+                               bfgs_minimize, inv_hessian_dense,
+                               lbfgsb_minimize, make_batched_value_and_grad)
 
 
 def rosen(x):
@@ -85,6 +85,42 @@ def test_already_converged_at_start():
                           LbfgsbOptions(pgtol=1e-6))
     assert np.all(np.asarray(res.status) == CONV_PGTOL)
     assert np.all(np.asarray(res.k) == 0)
+
+
+def test_done_round_marks_when_each_restart_stopped():
+    """done_round is the round count at the end of the iteration in which
+    a restart stopped: 1 for one converged at its start point, the whole
+    solve's rounds for the last one.  A restart converged at the start
+    takes no line-search round, so dropping its row leaves every output
+    of the other rows bitwise as it was, done_round included."""
+    B, D = 5, 4
+    x0 = jax.random.uniform(jax.random.PRNGKey(1), (B, D),
+                            minval=0.0, maxval=3.0, dtype=jnp.float64)
+    x0 = x0.at[2].set(1.0)                      # Rosenbrock's minimum
+    opts = LbfgsbOptions(maxiter=200, pgtol=1e-6, ftol=0.0)
+    res = lbfgsb_minimize(FB_ROSEN, x0, 0.0, 3.0, opts)
+    done = np.asarray(res.done_round)
+    assert int(res.k[2]) == 0 and done[2] == 1
+    assert done.max() == int(res.rounds)
+    assert np.all(done[np.asarray(res.k) > 0] > 1)
+    keep = np.array([0, 1, 3, 4])
+    fewer = lbfgsb_minimize(FB_ROSEN, x0[keep], 0.0, 3.0, opts)
+    assert int(fewer.rounds) == int(res.rounds)
+    for leaf in ("x", "f", "g", "k", "status", "n_evals", "done_round"):
+        np.testing.assert_array_equal(np.asarray(getattr(res, leaf))[keep],
+                                      np.asarray(getattr(fewer, leaf)))
+
+
+def test_done_round_of_capped_restarts_and_leading_batch():
+    """Restarts stopped at maxiter all leave in the last iteration; under
+    a leading batch shape done_round keeps that shape."""
+    fb = jax.vmap(FB_ROSEN)                      # (S, B, D) batches
+    x0 = jnp.full((2, 3, 5), 2.0, jnp.float64)
+    res = lbfgsb_minimize(fb, x0, 0.0, 3.0,
+                          LbfgsbOptions(maxiter=3, pgtol=1e-14, ftol=0.0))
+    assert res.done_round.shape == (2, 3)
+    assert np.all(np.asarray(res.status) == CONV_MAXITER)
+    assert np.all(np.asarray(res.done_round) == int(res.rounds))
 
 
 def test_maxiter_respected():

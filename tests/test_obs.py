@@ -1,6 +1,6 @@
 """Unified telemetry plane tests: tracer semantics (off-by-default,
-ring bounds, thread safety), ProgramTimer passthrough, the metrics
-registry + Prometheus exposition, the unified ``stats_snapshot()``
+ring bounds, thread safety), ProgramTimer passthrough, the fleet and
+service spans and lockstep counters, the unified ``stats_snapshot()``
 schema contract across all five engine layers, retrace-report merging
 and the retrace-history cap, the AskEngine NaN guard, and Chrome-trace
 export from both live tracers and WAL journals."""
@@ -66,6 +66,17 @@ def test_tracer_span_and_instant_shapes():
     assert sp["ts"] <= inst["ts"]
 
 
+def test_span_body_extends_args():
+    """The body gets the span's args and may add what it learns by the
+    end; with tracing off it gets the dict all the same."""
+    with obs_trace.span("off", a=1) as args:
+        args["b"] = 2
+    tr = obs_trace.enable()
+    with obs_trace.span("solve", bucket=8) as args:
+        args.update(rounds=5)
+    assert tr.events()[0]["args"] == {"bucket": 8, "rounds": 5}
+
+
 def test_tracer_ring_drops_oldest():
     tr = obs_trace.enable(capacity=8)
     for i in range(20):
@@ -126,51 +137,6 @@ def test_program_timer_passthrough_and_spans():
     assert inner.n_calls == 2
 
 
-# =============================================================== metrics
-def test_counter_gauge_labels():
-    reg = obs_metrics.MetricsRegistry()
-    c = reg.counter("asks", "total asks")
-    c.inc(labels={"tenant": "a"})
-    c.inc(2, labels={"tenant": "a"})
-    c.inc(labels={"tenant": "b"})
-    assert c.value(labels={"tenant": "a"}) == 3
-    assert c.value(labels={"tenant": "b"}) == 1
-    assert c.value() == 0
-    with pytest.raises(ValueError):
-        c.inc(-1)
-    g = reg.gauge("depth")
-    g.set(5)
-    g.inc(2)
-    assert g.value() == 7
-    with pytest.raises(TypeError):
-        reg.gauge("asks")                   # name already a counter
-
-
-def test_histogram_percentiles():
-    h = obs_metrics.Histogram("lat_ms")
-    assert h.quantile(0.5) is None          # empty series
-    for v in range(1, 101):                 # 1..100 ms
-        h.observe(float(v))
-    p = h.percentiles()
-    assert p["p50"] <= p["p95"] <= p["p99"]
-    assert 25 <= p["p50"] <= 75             # bucket-resolution p50
-    assert p["p99"] <= 250                  # winning bucket's bound
-
-
-def test_prometheus_exposition():
-    reg = obs_metrics.MetricsRegistry()
-    reg.counter("repro_asks", "asks served").inc(3, labels={"tenant": "a"})
-    reg.gauge("repro_depth").set(2)
-    reg.histogram("repro_lat_ms").observe(0.7)
-    text = reg.render_prometheus()
-    assert "# TYPE repro_asks counter" in text
-    assert 'repro_asks{tenant="a"} 3' in text
-    assert "repro_depth 2" in text
-    assert 'repro_lat_ms_bucket{le="1"} 1' in text
-    assert 'repro_lat_ms_bucket{le="+Inf"} 1' in text
-    assert "repro_lat_ms_count 1" in text
-
-
 # ============================================= snapshot schema (sat. 1)
 def _fleet_kw(**over):
     kw = dict(n_startup_trials=4, n_restarts=4, pad_multiple=8, slots=4,
@@ -216,6 +182,15 @@ def test_snapshot_schema_all_layers(tmp_path):
     snap = svc.stats_snapshot()
     assert "journal_seq" in snap
     assert v("bo_service", snap) == []
+    # the lockstep and eager-update counters are part of the contract
+    for key in ("n_mso_solves", "n_mso_iters", "n_mso_ls_rounds",
+                "n_mso_study_rounds", "n_mso_study_wait_rounds",
+                "n_mso_capped_lanes", "n_eager_updates"):
+        assert key in obs_metrics.FLEET_ENGINE_KEYS
+        assert isinstance(snap[key], int)
+        bad = dict(snap)
+        bad.pop(key)
+        assert v("bo_service", bad)
 
 
 def test_validate_snapshot_flags_drift():
@@ -229,23 +204,57 @@ def test_validate_snapshot_flags_drift():
     assert obs_metrics.validate_snapshot("nope", good)
 
 
-def test_ingest_snapshot_flattens_to_gauges():
-    reg = obs_metrics.MetricsRegistry()
-    snap = {"n_steps": 4, "queue_depth": 2,
-            "retraces": {"causes": {"first-trace": 3, "shape": 1},
-                         "by_program": {}},
-            "svc_rung": "degrade",
-            "svc_tenants": {"a": {"served": 5, "is_shed": False,
-                                  "weight": 1.5}}}
-    obs_metrics.ingest_snapshot(reg, "bo_service", snap,
-                                labels={"study": 0})
-    base = {"component": "bo_service", "study": "0"}
-    assert reg.gauge("repro_n_steps").value(labels=base) == 4
-    assert reg.gauge("repro_retraces").value(
-        labels=dict(base, cause="shape")) == 1
-    assert reg.gauge("repro_tenant_served").value(
-        labels=dict(base, tenant="a")) == 5
-    assert reg.gauge("repro_svc_rung_index").value(labels=base) == 2
+def test_fleet_stage_spans_and_solve_args(tmp_path):
+    """A traced service step nests ``fleet.prefetch`` (observation sync
+    and requests) and ``fleet.deliver`` (collect and journal) inside
+    ``fleet.ask_batch``, and each ``fleet.step_block`` span carries its
+    solve's lockstep numbers, which add up to the counters."""
+    clock = VirtualClock()
+    fs = FleetSampler([BoxSpace.cube(2, 0.0, 1.0)] * 2, seed=0,
+                      journal_dir=str(tmp_path), sleep_fn=clock.sleep,
+                      **_fleet_kw())
+    owner = {0: "a", 1: "b"}
+    svc = BOService(fs, [TenantConfig(t, studies=(s,))
+                         for s, t in owner.items()], clock=clock)
+
+    def round_():
+        reqs = [svc.submit_ask(t, s) for s, t in owner.items()]
+        assert svc.service_step() == 2
+        for (s, t), r in zip(owner.items(), reqs):
+            svc.submit_tell(t, s, r.result.trial_id, _sphere(r.result.x))
+
+    for _ in range(4):                       # random start-up trials
+        round_()
+    before = svc.stats_snapshot()
+    tr = obs_trace.enable()
+    for _ in range(2):                       # two GP rounds
+        round_()
+    after = svc.stats_snapshot()
+    evs = [e for e in tr.events() if e["ph"] == "X"]
+    named = {n: [e for e in evs if e["name"] == n]
+             for n in ("fleet.ask_batch", "fleet.prefetch", "fleet.deliver",
+                       "fleet.step", "fleet.step_block")}
+    assert all(len(v) == 2 for v in named.values())
+    for outer, pre, dlv, step in zip(named["fleet.ask_batch"],
+                                     named["fleet.prefetch"],
+                                     named["fleet.deliver"],
+                                     named["fleet.step"]):
+        end = outer["ts"] + outer["dur"]
+        assert outer["ts"] <= pre["ts"] and pre["ts"] + pre["dur"] <= \
+            step["ts"] <= step["ts"] + step["dur"] <= dlv["ts"]
+        assert dlv["ts"] + dlv["dur"] <= end + 1e-3
+    solves = [e["args"] for e in named["fleet.step_block"]]
+    for a in solves:
+        assert a["rounds"] == 1 + a["iters"] + a["ls_rounds"]
+        assert 0 <= a["wait_rounds"] < a["rounds"]
+    for key, arg in (("n_rounds", "rounds"), ("n_mso_iters", "iters"),
+                     ("n_mso_ls_rounds", "ls_rounds"),
+                     ("n_mso_study_wait_rounds", "wait_rounds"),
+                     ("n_mso_capped_lanes", "capped")):
+        assert after[key] - before[key] == sum(a[arg] for a in solves)
+    assert after["n_mso_solves"] - before["n_mso_solves"] == 2
+    # each GP round observes both studies' last trials in their slots
+    assert after["n_eager_updates"] - before["n_eager_updates"] == 2 * 2 * 2
 
 
 # ====================================== retrace accounting (sat. 2)

@@ -125,6 +125,47 @@ def test_deadline_shed_while_queued(tmp_path):
     assert again.done and again.result is not None
 
 
+def test_request_spans_record_submit_dispatch_and_end():
+    """With tracing on, every request that reaches a terminal state
+    records one ``svc.request`` span from its submit, keyed by ``rid``,
+    whose ``dispatch_us`` is its last dispatch (none if it never left
+    the queue)."""
+    from repro.obs import trace as obs_trace
+    tr = obs_trace.enable()
+    try:
+        svc, clock = _mk_service(
+            3, [TenantConfig("a", studies=(0, 1)),
+                TenantConfig("b", studies=(2,))],
+            fi=FaultInjector(ask_fail={1: 1}), backoff_base=0.1)
+        served = svc.submit_ask("a", 0)
+        retried = svc.submit_ask("a", 1)
+        late = svc.submit_ask("b", 2, deadline=0.5)
+        clock.advance(1.0)                 # late's budget is spent
+        for _ in range(10):
+            if retried.done:
+                break
+            svc.service_step()
+            clock.advance(0.5)             # release the backoff
+        spans = [e for e in tr.events() if e["name"] == "svc.request"]
+    finally:
+        obs_trace.disable()
+    by_rid = {sp["args"]["rid"]: sp for sp in spans}
+    assert len(spans) == len(by_rid) == 3
+    for req in (served, retried, late):
+        args = by_rid[req.rid]["args"]
+        assert (args["tenant"], args["study"]) == (req.tenant, req.study)
+        assert (args["state"], args["attempts"]) == (req.state,
+                                                    req.attempts)
+    assert by_rid[late.rid]["args"]["dispatch_us"] is None
+    assert by_rid[retried.rid]["args"]["attempts"] == 2
+    for req in (served, retried):
+        sp = by_rid[req.rid]
+        assert sp["ts"] <= sp["args"]["dispatch_us"] <= sp["ts"] + sp["dur"]
+    # the retry's last dispatch came after the first round's
+    assert (by_rid[retried.rid]["args"]["dispatch_us"]
+            > by_rid[served.rid]["args"]["dispatch_us"])
+
+
 def test_deadline_miss_in_flight_via_injected_latency(tmp_path):
     """A suggestion that comes back after its deadline (injected
     full-refit latency on the virtual clock) is cancel-and-shed: the
