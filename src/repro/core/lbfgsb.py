@@ -2,13 +2,17 @@
 
 This is the device-resident realization of the paper's D-BE scheme
 ("Decouple QN updates, Batch Evaluations"): every restart carries its own
-limited-memory state stacked along a leading batch axis ``(B, m, D)``, all
-restarts advance in lockstep inside one ``lax.while_loop``, and function
-evaluations for all *active* restarts happen in a single batched call.
-Because each restart's two-loop recursion reads only its own history slice,
-the implied inverse-Hessian approximation is block-diagonal **by
-construction** — the exact property the paper's coroutine buys on top of
-scipy, with zero per-iteration host round trips.
+limited-memory state stacked along a leading batch axis ``(B, m, D)`` and
+its own line search, and ONE ``lax.while_loop`` runs batched evaluation
+rounds.  In a round every running restart asks for the point its own
+L-BFGS-B needs next (a line search's next trial, or the first trial of
+its next iteration), and one batched call evaluates them all; no restart
+waits for another's line search, so a solve takes as many rounds as its
+longest restart takes evaluations.  Because each restart's two-loop
+recursion reads only its own history slice, the implied inverse-Hessian
+approximation is block-diagonal **by construction** — the exact property
+the paper's coroutine buys on top of scipy, with zero per-iteration host
+round trips.
 
 Algorithm: projected quasi-Newton (Schmidt et al.) — gradient projection for
 the bound active set + L-BFGS two-loop direction on the free variables +
@@ -70,6 +74,10 @@ class LbfgsbState(NamedTuple):
     n_evals: Array      # (B,) int32 per-restart *active* objective evals
     rounds: Array       # () int32 number of batched evaluation rounds
     done_round: Array   # (B,) int32 ``rounds`` when the restart stopped
+    d: Array            # (B, D) search direction of the iteration under way
+    t: Array            # (B,)  its line search's current trial step
+    tries: Array        # (B,) int32 trial points it has evaluated so far
+    mixed_rounds: Array  # () int32 rounds mixing retries and first trials
 
 
 class LbfgsbResult(NamedTuple):
@@ -79,10 +87,14 @@ class LbfgsbResult(NamedTuple):
     k: Array            # (B,) iterations taken
     status: Array       # (B,)
     n_evals: Array      # (B,)
-    rounds: Array       # () total batched rounds (line-search rounds incl.)
+    rounds: Array       # () total batched rounds; max(n_evals) on one
+                        # device, each lane evaluating once a round
     done_round: Array   # (B,) rounds at the end of the restart's last
                         # iteration (1 if converged at the start point);
                         # rounds - done_round is how long it sat frozen
+    mixed_rounds: Array  # () rounds in which one live lane retried a
+                         # line search while another took the first
+                         # trial of an iteration
     state: LbfgsbState  # final full state (history introspection)
 
 
@@ -185,6 +197,10 @@ def _init_state(fun_batched, x0, lower, upper, opts: LbfgsbOptions
         n_evals=jnp.ones((B,), jnp.int32),
         rounds=jnp.asarray(1, jnp.int32),
         done_round=jnp.zeros((B,), jnp.int32),
+        d=jnp.zeros((B, D), dt),
+        t=jnp.zeros((B,), dt),
+        tries=jnp.zeros((B,), jnp.int32),
+        mixed_rounds=jnp.asarray(0, jnp.int32),
     )
 
 
@@ -198,13 +214,11 @@ def _check_initial_convergence(state: LbfgsbState, lower, upper,
                                                state.done_round))
 
 
-def _step(fun_batched, lower, upper, opts: LbfgsbOptions,
-          state: LbfgsbState) -> LbfgsbState:
-    B, D = state.x.shape
-    dt = state.x.dtype
-    running = state.status == RUNNING                            # (B,)
-
-    # ---- search direction -------------------------------------------------
+def _direction(state: LbfgsbState, lower, upper, opts: LbfgsbOptions
+               ) -> Tuple[Array, Array]:
+    """Each lane's search direction at its iterate, and its first trial
+    step: unit for quasi-Newton steps, conservative on a cold start."""
+    B = state.x.shape[0]
     act = _active_mask(state.x, state.g, lower, upper, opts.bound_eps)
     gm = jnp.where(act, 0.0, state.g)
     s_ord, y_ord, rho_ord, valid = _ordered_history(state, opts.m)
@@ -215,57 +229,40 @@ def _step(fun_batched, lower, upper, opts: LbfgsbOptions,
     gnorm2 = jnp.einsum("bd,bd->b", gm, gm)
     bad = dg > -1e-12 * jnp.maximum(gnorm2, 1e-30)
     d = jnp.where(bad[:, None], -gm, d)
-    dg = jnp.where(bad, -gnorm2, dg)
-
-    # initial trial step: unit for QN steps, conservative on cold start
     dinf = jnp.max(jnp.abs(d), axis=-1)
     t0 = jnp.where((state.length == 0),
                    jnp.minimum(1.0, 1.0 / jnp.maximum(dinf, 1e-30)),
-                   jnp.ones((B,), dt))
+                   jnp.ones((B,), state.x.dtype))
+    return d, t0
 
-    # ---- projected backtracking Armijo line search (batched rounds) -------
-    class LS(NamedTuple):
-        t: Array; accepted: Array; x_new: Array; f_new: Array; g_new: Array
-        tries: Array; rounds: Array; n_evals: Array
 
-    def ls_cond(ls: LS):
-        return jnp.any(running & ~ls.accepted & (ls.tries < opts.maxls))
+def _round(fun_batched, lower, upper, opts: LbfgsbOptions,
+           state: LbfgsbState) -> LbfgsbState:
+    """One batched evaluation round.  Every running lane evaluates the
+    trial point of its own projected backtracking Armijo line search;
+    a lane that accepts (or exhausts ``maxls``) ends its iteration here
+    and, if still running, starts the next one at once."""
+    dt = state.x.dtype
+    running = state.status == RUNNING                            # (B,)
 
-    def ls_body(ls: LS):
-        x_trial = _proj(state.x + ls.t[:, None] * d, lower, upper)
-        # frozen/accepted rows re-evaluate their accepted point (lockstep);
-        # their result is discarded by the mask below.
-        f_t, g_t = fun_batched(x_trial)
-        step_vec = x_trial - state.x
-        gs = jnp.einsum("bd,bd->b", state.g, step_vec)
-        armijo = f_t <= state.f + opts.armijo_c1 * gs
-        # accept also if projection collapsed the step to ~zero (stuck)
-        stuck = jnp.max(jnp.abs(step_vec), axis=-1) <= 1e-30
-        newly = running & ~ls.accepted & (armijo | stuck)
-        take = newly[:, None]
-        evals = running & ~ls.accepted
-        return LS(
-            t=jnp.where(newly | ls.accepted, ls.t, ls.t * opts.ls_shrink),
-            accepted=ls.accepted | newly | stuck,
-            x_new=jnp.where(take, x_trial, ls.x_new),
-            f_new=jnp.where(newly, f_t, ls.f_new),
-            g_new=jnp.where(take, g_t, ls.g_new),
-            tries=ls.tries + evals.astype(jnp.int32),
-            rounds=ls.rounds + 1,
-            n_evals=ls.n_evals + evals.astype(jnp.int32),
-        )
-
-    ls0 = LS(t=t0, accepted=~running, x_new=state.x, f_new=state.f,
-             g_new=state.g, tries=jnp.zeros((B,), jnp.int32),
-             rounds=jnp.asarray(0, jnp.int32),
-             n_evals=jnp.zeros((B,), jnp.int32))
-    ls = lax.while_loop(ls_cond, ls_body, ls0)
-
-    ls_failed = running & ~ls.accepted
+    x_trial = jnp.where(
+        running[:, None],
+        _proj(state.x + state.t[:, None] * state.d, lower, upper), state.x)
+    # stopped lanes re-evaluate their own point; the mask discards it
+    f_t, g_t = fun_batched(x_trial)
+    step_vec = x_trial - state.x
+    gs = jnp.einsum("bd,bd->b", state.g, step_vec)
+    armijo = f_t <= state.f + opts.armijo_c1 * gs
+    # accept also if projection collapsed the step to ~zero (stuck)
+    stuck = jnp.max(jnp.abs(step_vec), axis=-1) <= 1e-30
+    accepted = running & (armijo | stuck)
+    tries = state.tries + running.astype(jnp.int32)
+    ls_failed = running & ~accepted & (tries >= opts.maxls)
+    ends = accepted | ls_failed                  # the lane's iteration ends
     # on failure keep the old iterate
-    x_new = jnp.where(ls_failed[:, None], state.x, ls.x_new)
-    f_new = jnp.where(ls_failed, state.f, ls.f_new)
-    g_new = jnp.where(ls_failed[:, None], state.g, ls.g_new)
+    x_new = jnp.where(accepted[:, None], x_trial, state.x)
+    f_new = jnp.where(accepted, f_t, state.f)
+    g_new = jnp.where(accepted[:, None], g_t, state.g)
 
     # ---- curvature-pair update (masked, circular buffer) ------------------
     s_vec = x_new - state.x
@@ -275,7 +272,7 @@ def _step(fun_batched, lower, upper, opts: LbfgsbOptions,
     ss = jnp.einsum("bd,bd->b", s_vec, s_vec)
     curv_ok = sy > opts.curv_eps * jnp.sqrt(
         jnp.maximum(ss, 1e-300) * jnp.maximum(yy, 1e-300))
-    do_push = running & ~ls_failed & curv_ok
+    do_push = accepted & curv_ok
 
     full = state.length == opts.m
     slot = (state.start + state.length % opts.m) % opts.m        # write pos
@@ -293,51 +290,65 @@ def _step(fun_batched, lower, upper, opts: LbfgsbOptions,
                        state.length)
     gamma = jnp.where(do_push, sy / jnp.maximum(yy, 1e-300), state.gamma)
 
-    # ---- convergence tests -------------------------------------------------
+    # ---- convergence tests, for lanes whose iteration ended ---------------
     pg = projected_grad(x_new, g_new, lower, upper)
     conv_pg = jnp.max(jnp.abs(pg), axis=-1) <= opts.pgtol
     denom = jnp.maximum(jnp.maximum(jnp.abs(state.f), jnp.abs(f_new)), 1.0)
     conv_f = (opts.ftol > 0) & ((state.f - f_new) <= opts.ftol * denom)
-    k_new = state.k + running.astype(jnp.int32)
+    k_new = state.k + ends.astype(jnp.int32)
     conv_it = k_new >= opts.maxiter
 
     status = state.status
-    status = jnp.where(running & conv_pg, CONV_PGTOL, status)
-    status = jnp.where(running & ~conv_pg & conv_f, CONV_FTOL, status)
-    status = jnp.where(running & (status == RUNNING) & ls_failed,
+    status = jnp.where(ends & conv_pg, CONV_PGTOL, status)
+    status = jnp.where(ends & ~conv_pg & conv_f, CONV_FTOL, status)
+    status = jnp.where(ends & (status == RUNNING) & ls_failed,
                        CONV_LS_FAIL, status)
-    status = jnp.where(running & (status == RUNNING) & conv_it,
+    status = jnp.where(ends & (status == RUNNING) & conv_it,
                        CONV_MAXITER, status)
+    status = status.astype(jnp.int32)
 
-    keep = running[:, None]
-    rounds = state.rounds + ls.rounds
-    return LbfgsbState(
-        x=jnp.where(keep, x_new, state.x),
-        f=jnp.where(running, f_new, state.f),
-        g=jnp.where(keep, g_new, state.g),
+    # a round in which one live lane retries while another takes the
+    # first trial of an iteration; a solver that ends every lane's line
+    # search before any lane's next iteration has no such round
+    retry = running & (state.tries > 0)
+    mixed = jnp.any(retry) & jnp.any(running & ~retry)
+    rounds = state.rounds + 1
+    new = state._replace(
+        x=x_new, f=f_new, g=g_new,
         s_hist=s_hist, y_hist=y_hist, rho=rho,
         start=start, length=length, gamma=gamma,
-        k=k_new, status=status.astype(jnp.int32),
-        n_evals=state.n_evals + ls.n_evals,
+        k=k_new, status=status,
+        n_evals=state.n_evals + running.astype(jnp.int32),
         rounds=rounds,
-        done_round=jnp.where(running & (status != RUNNING), rounds,
+        done_round=jnp.where(ends & (status != RUNNING), rounds,
                              state.done_round),
-    )
+        mixed_rounds=state.mixed_rounds + mixed.astype(jnp.int32))
+
+    # ---- the next iteration's direction, for lanes that go on -------------
+    d_next, t0 = _direction(new, lower, upper, opts)
+    again = ends & (status == RUNNING)
+    return new._replace(
+        d=jnp.where(again[:, None], d_next, state.d),
+        t=jnp.where(again, t0, jnp.where(running & ~ends,
+                                         state.t * opts.ls_shrink, state.t)),
+        tries=jnp.where(ends, 0, tries))
 
 
 def _minimize_2d(fun_batched, x0, lower, upper,
                  options: LbfgsbOptions) -> LbfgsbResult:
-    """The core (B, D) lockstep solve (see :func:`lbfgsb_minimize`)."""
+    """The core (B, D) solve (see :func:`lbfgsb_minimize`)."""
     state = _init_state(fun_batched, x0, lower, upper, options)
     state = _check_initial_convergence(state, lower, upper, options)
+    d, t0 = _direction(state, lower, upper, options)
+    state = state._replace(d=d, t=t0)
 
-    step = functools.partial(_step, fun_batched, lower, upper, options)
+    step = functools.partial(_round, fun_batched, lower, upper, options)
     state = lax.while_loop(
         lambda s: jnp.any(s.status == RUNNING), step, state)
     return LbfgsbResult(x=state.x, f=state.f, g=state.g, k=state.k,
                         status=state.status, n_evals=state.n_evals,
                         rounds=state.rounds, done_round=state.done_round,
-                        state=state)
+                        mixed_rounds=state.mixed_rounds, state=state)
 
 
 def lbfgsb_minimize(
@@ -347,15 +358,16 @@ def lbfgsb_minimize(
     upper: Array,
     options: LbfgsbOptions = LbfgsbOptions(),
 ) -> LbfgsbResult:
-    """Minimize independent D-dimensional problems in lockstep.
+    """Minimize independent D-dimensional problems in shared rounds.
 
     The batch may carry an *arbitrary leading shape*: ``x0`` of shape
     ``(*batch, D)`` runs ``prod(batch)`` problems through ONE
     ``lax.while_loop`` (the fleet-ask requirement: a ``(S, B, D)`` fleet
-    of studies × restarts shares its QN iterations and line-search
-    rounds, instead of vmapping S separate ``while_loop``s).  Every
-    result leaf leads with ``batch`` again; ``rounds`` stays a scalar
-    (rounds are shared by construction).
+    of studies × restarts shares its evaluation rounds, instead of
+    vmapping S separate ``while_loop``s).  Every result leaf leads with
+    ``batch`` again; ``rounds`` and ``mixed_rounds`` stay scalars (rounds
+    are shared by construction).  Each problem's result is what solving
+    it alone gives.
 
     Args:
       fun_batched: maps ``(*batch, D)`` → ``(batch values, (*batch, D)
@@ -366,6 +378,8 @@ def lbfgsb_minimize(
     """
     if x0.ndim < 2:
         raise ValueError(f"x0 must be (*batch, D), got {x0.shape}")
+    if options.maxls < 1:
+        raise ValueError(f"maxls must be >= 1, got {options.maxls}")
     lower = jnp.broadcast_to(jnp.asarray(lower, x0.dtype), x0.shape)
     upper = jnp.broadcast_to(jnp.asarray(upper, x0.dtype), x0.shape)
     if x0.ndim == 2:

@@ -365,8 +365,11 @@ class FleetEngine:
         # wait rounds are those its restarts sat frozen after the last
         # of them stopped, while other lanes kept the loop running
         self.n_mso_solves = 0
-        self.n_mso_iters = 0             # outer iterations (max k)
-        self.n_mso_ls_rounds = 0         # rounds past one per iteration
+        self.n_mso_iters = 0             # deepest lane's iterations (max k)
+        self.n_mso_ls_rounds = 0         # rounds past the deepest lane's
+                                         # one per iteration
+        self.n_mso_mixed_rounds = 0      # rounds in which one lane retried
+                                         # while another began an iteration
         self.n_mso_study_rounds = 0      # rounds x requesting studies
         self.n_mso_study_wait_rounds = 0
         self.n_mso_capped_lanes = 0      # requesting lanes at maxiter or
@@ -602,6 +605,7 @@ class FleetEngine:
             "n_mso_solves": self.n_mso_solves,
             "n_mso_iters": self.n_mso_iters,
             "n_mso_ls_rounds": self.n_mso_ls_rounds,
+            "n_mso_mixed_rounds": self.n_mso_mixed_rounds,
             "n_mso_study_rounds": self.n_mso_study_rounds,
             "n_mso_study_wait_rounds": self.n_mso_study_wait_rounds,
             "n_mso_capped_lanes": self.n_mso_capped_lanes,
@@ -970,7 +974,7 @@ class FleetEngine:
             jnp.asarray(keys), blk.x, blk.y, nv, blk.theta, blk.chol,
             blk.alpha, blk.kinv)
         bx = np.asarray(best_x)                     # ONE (S, D) transfer
-        k_arr, ev_arr, rounds, bacq, status, done_round = stats
+        k_arr, ev_arr, rounds, bacq, status, done_round, mixed = stats
         # rounds is per-slot: each slot reports its own device's lockstep
         # round count (devices loop independently on a mesh; on one
         # device every slot sees the same shared count)
@@ -993,22 +997,26 @@ class FleetEngine:
                                             int(rounds.max()), ev_live)
         solve.update(self._count_lockstep(
             [s for s, _ in req], rounds, np.asarray(k_arr),
-            np.asarray(status), np.asarray(done_round)))
+            np.asarray(status), np.asarray(done_round), np.asarray(mixed)))
         return len(req)
 
     def _count_lockstep(self, req_slots: List[int], rounds: np.ndarray,
                         k: np.ndarray, status: np.ndarray,
-                        done_round: np.ndarray) -> Dict[str, int]:
-        """Fold one MSO solve into the lockstep counters.  ``rounds`` is
-        per slot, ``k``/``status``/``done_round`` per slot and restart.
-        On a mesh each device loops on its own; the solve's rounds, as in
-        ``n_rounds``, are those of the device that looped longest, and a
-        study waits on its own device's loop."""
+                        done_round: np.ndarray, mixed: np.ndarray
+                        ) -> Dict[str, int]:
+        """Fold one MSO solve into the lockstep counters.  ``rounds`` and
+        ``mixed`` are per slot, ``k``/``status``/``done_round`` per slot
+        and restart.  On a mesh each device loops on its own; the solve's
+        rounds, as in ``n_rounds``, are those of the device that looped
+        longest, and a study waits on its own device's loop.  The deepest
+        lane alone takes ``1 + k`` evaluations, so ``ls_rounds``, the
+        rounds beyond its iterations, is never negative."""
         lanes = self.cfg.slots                   # slots per device
         d = int(np.argmax(rounds)) // lanes
         n_rounds = int(rounds.max())
         iters = int(k[d * lanes:(d + 1) * lanes].max())
         ls_rounds = n_rounds - 1 - iters
+        n_mixed = int(mixed[d * lanes])
         r = rounds[req_slots]
         wait = int(np.sum(r - done_round[req_slots].max(axis=1)))
         capped = int(np.isin(status[req_slots],
@@ -1016,11 +1024,12 @@ class FleetEngine:
         self.n_mso_solves += 1
         self.n_mso_iters += iters
         self.n_mso_ls_rounds += ls_rounds
+        self.n_mso_mixed_rounds += n_mixed
         self.n_mso_study_rounds += int(r.sum())
         self.n_mso_study_wait_rounds += wait
         self.n_mso_capped_lanes += capped
         return {"rounds": n_rounds, "iters": iters, "ls_rounds": ls_rounds,
-                "wait_rounds": wait, "capped": capped}
+                "wait_rounds": wait, "capped": capped, "mixed": n_mixed}
 
     # ------------------------------------------------------- device side
     def _full_impl(self, x, y, n_valid, thetas, tlo, tup, do_full,
@@ -1082,7 +1091,8 @@ class FleetEngine:
 
     def _mso_impl(self, keys, x, y, n_valid, theta, chol, alpha, kinv):
         """The fleet MSO tail: per-slot restart sampling feeds ONE
-        (S, B, D) lockstep solve; per-slot argmax selects suggestions."""
+        (S, B, D) solve in shared rounds; per-slot argmax selects
+        suggestions."""
         cfg = self.cfg
         b = x.shape[1]
 
@@ -1108,5 +1118,6 @@ class FleetEngine:
         # (independent) round count, and every output leads with the
         # slot axis so one P(study) out-spec covers the whole pytree
         rounds = jnp.full((x.shape[0],), res.rounds)
+        mixed = jnp.full((x.shape[0],), res.mixed_rounds)
         return best_x, (res.k, res.n_evals, rounds, best_acq, res.status,
-                        res.done_round)
+                        res.done_round, mixed)

@@ -38,6 +38,7 @@ FLEET_ENGINE_KEYS = frozenset({
     "n_migrations_intra", "n_migrations_cross", "n_rejected", "n_shed",
     "n_quarantined", "n_parked", "n_retries", "n_retry_backoffs",
     "backoff_total_s", "n_mso_solves", "n_mso_iters", "n_mso_ls_rounds",
+    "n_mso_mixed_rounds",
     "n_mso_study_rounds", "n_mso_study_wait_rounds", "n_mso_capped_lanes",
     "n_eager_updates", "n_devices", "slots_per_device", "queue_depth",
     "n_full_compiles", "n_incr_compiles", "n_mso_compiles",

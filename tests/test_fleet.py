@@ -386,18 +386,20 @@ def _spy_lockstep(fleet):
     seen = []
     orig = fleet._count_lockstep
 
-    def spy(req_slots, rounds, k, status, done_round):
+    def spy(req_slots, rounds, k, status, done_round, mixed):
         seen.append({s: (int(rounds[s]), int(done_round[s].max()))
                      for s in req_slots})
-        return orig(req_slots, rounds, k, status, done_round)
+        return orig(req_slots, rounds, k, status, done_round, mixed)
     fleet._count_lockstep = spy
     return seen
 
 
 def test_fleet_lockstep_counters_add_up():
-    """Every lockstep round is the start round, an outer iteration or a
-    line-search retry; each requesting study waits the rounds after its
-    last restart stopped, so the slowest study waits none."""
+    """Every round is the start round, one of the deepest restart's
+    iterations or a round beyond them; at most every round but the start
+    mixes one restart's line-search retry with another's new iteration;
+    each requesting study waits the rounds after its last restart
+    stopped, so the slowest study waits none."""
     fleet = _counted_fleet()
     seen = _spy_lockstep(fleet)
     before = {**fleet.engine.stats_snapshot(), **fleet.stats_snapshot()}
@@ -411,12 +413,13 @@ def test_fleet_lockstep_counters_add_up():
     after = {**fleet.engine.stats_snapshot(), **fleet.stats_snapshot()}
     d = {k: after[k] - before[k] for k in (
         "n_rounds", "n_mso_solves", "n_mso_iters", "n_mso_ls_rounds",
-        "n_mso_study_rounds", "n_mso_study_wait_rounds",
-        "n_mso_capped_lanes", "n_steps")}
+        "n_mso_mixed_rounds", "n_mso_study_rounds",
+        "n_mso_study_wait_rounds", "n_mso_capped_lanes", "n_steps")}
     assert d["n_mso_solves"] == d["n_steps"] == 3
     assert d["n_rounds"] == (d["n_mso_solves"] + d["n_mso_iters"]
                              + d["n_mso_ls_rounds"])
     assert d["n_mso_iters"] > 0 and d["n_mso_ls_rounds"] >= 0
+    assert 0 <= d["n_mso_mixed_rounds"] <= d["n_rounds"] - d["n_mso_solves"]
     assert d["n_mso_study_rounds"] == 3 * d["n_rounds"]
     assert 0 <= d["n_mso_capped_lanes"] <= 3 * 3 * fleet.cfg.n_restarts
     waits = [{s: r - last for s, (r, last) in solve.items()}

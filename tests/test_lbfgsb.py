@@ -7,9 +7,10 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 from scipy.optimize import minimize
 
-from repro.core.lbfgsb import (CONV_MAXITER, CONV_PGTOL, LbfgsbOptions,
-                               bfgs_minimize, inv_hessian_dense,
-                               lbfgsb_minimize, make_batched_value_and_grad)
+from repro.core.lbfgsb import (CONV_FTOL, CONV_LS_FAIL, CONV_MAXITER,
+                               CONV_PGTOL, LbfgsbOptions, bfgs_minimize,
+                               inv_hessian_dense, lbfgsb_minimize,
+                               make_batched_value_and_grad)
 
 
 def rosen(x):
@@ -121,6 +122,99 @@ def test_done_round_of_capped_restarts_and_leading_batch():
     assert res.done_round.shape == (2, 3)
     assert np.all(np.asarray(res.status) == CONV_MAXITER)
     assert np.all(np.asarray(res.done_round) == int(res.rounds))
+
+
+# A bounded Rosenbrock whose lanes backtrack different numbers of times:
+# lane 1 starts at the minimum, lane 3's box pins it on its bounds, and
+# maxls=4 drives lane 5 to a failed line search.
+SPREAD_OPTS = LbfgsbOptions(maxiter=200, pgtol=1e-6, ftol=0.0, maxls=4)
+# the MAP fit's tolerances (FIT_OPTS): lane 5's failure lands on CONV_FTOL
+SPREAD_FTOL_OPTS = SPREAD_OPTS._replace(maxiter=60, pgtol=1e-5, ftol=1e-12)
+# rounds the solver with a per-iteration barrier (every lane's line search
+# over before any lane's next iteration) took on SPREAD_OPTS's problem
+BARRIER_ROUNDS = 62
+LEAVES = ("x", "f", "g", "k", "status", "n_evals", "done_round")
+
+
+def _spread_problem():
+    B, D = 6, 4
+    x0 = jax.random.uniform(jax.random.PRNGKey(7), (B, D), minval=-2.0,
+                            maxval=2.0, dtype=jnp.float64)
+    x0 = x0.at[1].set(1.0)
+    lower = jnp.full((B, D), -2.0, jnp.float64).at[3].set(1.5)
+    upper = jnp.full((B, D), 2.0, jnp.float64)
+    return x0, lower, upper
+
+
+@pytest.mark.parametrize("case", ["spread", "ftol", "leading"])
+def test_each_lane_solves_as_if_alone(case):
+    """Every lane of a shared-round solve gives what solving it alone
+    gives: bitwise against the lane solved alone at the same batch width
+    (every lane a copy of it), ``done_round`` equal to that solve's
+    rounds.  Against ``B=1`` solves the integer leaves are equal too;
+    XLA's CPU dot at batch 1 sums in another order, so the floats agree
+    to rounding, which the failed line search's lane carries to ~1e-10
+    (as it does under the solver with a per-iteration barrier)."""
+    x0, lower, upper = _spread_problem()
+    opts = SPREAD_FTOL_OPTS if case == "ftol" else SPREAD_OPTS
+    fun = FB_ROSEN
+    if case == "leading":                       # (S, B, D) = (2, 3, 4)
+        x0, lower, upper = (a.reshape(2, 3, 4) for a in (x0, lower, upper))
+        fun = jax.vmap(FB_ROSEN)
+    solve = jax.jit(lambda x, lo, up: lbfgsb_minimize(fun, x, lo, up, opts))
+    res = solve(x0, lower, upper)
+    status = np.asarray(res.status).reshape(-1)
+    assert status[1] == CONV_PGTOL and np.asarray(res.k).reshape(-1)[1] == 0
+    assert status[5] == (CONV_FTOL if case == "ftol" else CONV_LS_FAIL)
+    x3 = np.asarray(res.x).reshape(6, 4)[3]
+    assert np.all((x3 == 1.5) | (x3 == 2.0)) and np.any(x3 == 1.5)
+    n_evals = np.asarray(res.n_evals).reshape(-1)
+    assert len(set(n_evals.tolist())) == 6      # six different schedules
+    lanes = np.ndindex(x0.shape[:-1])
+    for i, lane in enumerate(lanes):
+        alone = solve(*(jnp.broadcast_to(a[lane], a.shape)
+                        for a in (x0, lower, upper)))
+        one = lbfgsb_minimize(FB_ROSEN, x0[lane][None], lower[lane][None],
+                              upper[lane][None], opts)
+        assert int(alone.rounds) == int(one.rounds) == n_evals[i]
+        for leaf in LEAVES:
+            got = np.asarray(getattr(res, leaf))[lane]
+            np.testing.assert_array_equal(
+                got, np.asarray(getattr(alone, leaf))[lane], err_msg=leaf)
+            if leaf in ("x", "f", "g"):
+                np.testing.assert_allclose(
+                    got, np.asarray(getattr(one, leaf))[0], rtol=0.0,
+                    atol=1e-8, err_msg=leaf)
+            elif leaf == "done_round":
+                assert got == int(one.rounds)
+            else:
+                assert got == np.asarray(getattr(one, leaf))[0], leaf
+
+
+def test_rounds_are_the_longest_lanes_evaluations():
+    """No lane waits for another's line search: the shared rounds are the
+    most evaluations any one lane takes, fewer than the barrier took."""
+    x0, lower, upper = _spread_problem()
+    res = lbfgsb_minimize(FB_ROSEN, x0, lower, upper, SPREAD_OPTS)
+    n_evals = np.asarray(res.n_evals)
+    assert int(res.rounds) == n_evals.max() == np.asarray(
+        res.done_round).max()
+    assert int(res.rounds) < BARRIER_ROUNDS
+    # each lane stops at the round of its own last evaluation
+    np.testing.assert_array_equal(np.asarray(res.done_round), n_evals)
+
+
+@pytest.mark.parametrize("lanes", [1, 6])
+def test_mixed_rounds(lanes):
+    """A lone lane never mixes a retry with a first trial; the spread
+    problem's lanes do, in some rounds and not in all."""
+    x0, lower, upper = (a[:lanes] for a in _spread_problem())
+    res = lbfgsb_minimize(FB_ROSEN, x0, lower, upper, SPREAD_OPTS)
+    assert res.mixed_rounds.ndim == 0
+    if lanes == 1:
+        assert int(res.mixed_rounds) == 0
+    else:
+        assert 0 < int(res.mixed_rounds) < int(res.rounds) - 1
 
 
 def test_maxiter_respected():

@@ -184,8 +184,9 @@ def test_snapshot_schema_all_layers(tmp_path):
     assert v("bo_service", snap) == []
     # the lockstep and eager-update counters are part of the contract
     for key in ("n_mso_solves", "n_mso_iters", "n_mso_ls_rounds",
-                "n_mso_study_rounds", "n_mso_study_wait_rounds",
-                "n_mso_capped_lanes", "n_eager_updates"):
+                "n_mso_mixed_rounds", "n_mso_study_rounds",
+                "n_mso_study_wait_rounds", "n_mso_capped_lanes",
+                "n_eager_updates"):
         assert key in obs_metrics.FLEET_ENGINE_KEYS
         assert isinstance(snap[key], int)
         bad = dict(snap)
@@ -247,12 +248,17 @@ def test_fleet_stage_spans_and_solve_args(tmp_path):
     for a in solves:
         assert a["rounds"] == 1 + a["iters"] + a["ls_rounds"]
         assert 0 <= a["wait_rounds"] < a["rounds"]
+        assert 0 <= a["mixed"] <= a["rounds"] - 1
     for key, arg in (("n_rounds", "rounds"), ("n_mso_iters", "iters"),
                      ("n_mso_ls_rounds", "ls_rounds"),
                      ("n_mso_study_wait_rounds", "wait_rounds"),
-                     ("n_mso_capped_lanes", "capped")):
+                     ("n_mso_capped_lanes", "capped"),
+                     ("n_mso_mixed_rounds", "mixed")):
         assert after[key] - before[key] == sum(a[arg] for a in solves)
     assert after["n_mso_solves"] - before["n_mso_solves"] == 2
+    assert 0 <= after["n_mso_mixed_rounds"] - before["n_mso_mixed_rounds"] \
+        <= (after["n_rounds"] - before["n_rounds"]
+            - (after["n_mso_solves"] - before["n_mso_solves"]))
     # each GP round observes both studies' last trials in their slots
     assert after["n_eager_updates"] - before["n_eager_updates"] == 2 * 2 * 2
 
