@@ -4,12 +4,12 @@
     python3 bench/control.py --workload <cell> SEED [SEED ...]
 
 For each seed, one run of the cell on the chip at its own size (set-up and
-its window's fixed work), then ``check_numbers`` twice over what that
-window served: once for the program, and once for the control, which is
-the reference in float32 put in the program's place (the next precision
-below the float64 the deployment states).  One JSON line per seed; the
-seeds share the process, so only the first compiles.  The benchmark's
-own runs never run the control.  Limits are set from these readings, as
+its window's fixed work), then the deployment module's ``check_numbers``
+twice over what that window served: once for the program, and once for
+the control, which is the reference in float32 put in the program's
+place (the next precision below the float64 the deployment states).
+One JSON line per seed; the seeds share the process, so only the first
+compiles.  The benchmark's own runs never run the control.  Limits are set from these readings, as
 ``PERF.md`` records.
 """
 import time
@@ -44,6 +44,7 @@ def main(argv=None) -> int:
     except harness.BenchError as e:
         print(f"control: {e}", file=sys.stderr)
         return 2
+    check = harness.deployment_of(cell["config"]).check_numbers
     run_dir = harness.RUNS / f"{args.workload}.control"
     t_process = T_PROCESS
     with CompileMeter() as meter:
@@ -52,8 +53,8 @@ def main(argv=None) -> int:
                 run = harness.run_cell(cell["config"], cell["mix"],
                                        seed=seed, trace=False, meter=meter,
                                        t_process=t_process, run_dir=run_dir)
-                program = harness.check_numbers(run)
-                control = harness.check_numbers(run, dtype=np.float32)
+                program = check(run)
+                control = check(run, dtype=np.float32)
             finally:
                 shutil.rmtree(run_dir, ignore_errors=True)
             print(json.dumps({"workload": args.workload, "seed": seed,

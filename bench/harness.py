@@ -1,41 +1,69 @@
 """One run of one benchmark cell: set-up, the measured window, the check.
 
-The cell names a configuration (``bench/configs/<config>.json``: the
-deployment) and a traffic mix (``bench/traffic/<mix>.json``).  Each metric
-of ``BENCHMARK.json`` is read by ``bench/metrics/<metric>.py``.  The harness
-knows none of them by name: a later cell, mix or metric is new files.
+The cell names a configuration (``bench/configs/<config>.json``) and a
+traffic mix (``bench/traffic/<mix>.json``).  The configuration names its
+deployment, ``bench/deployments/<deployment>.py``, which builds the
+served path and judges what it served.  Each metric of
+``BENCHMARK.json`` is read by ``bench/metrics/<metric>.py``.  The harness
+knows none of them by name: a later cell, mix or metric is new files,
+and so is a configuration of another shape (studies of several
+dimensions, several fleets, other search spaces): a new deployment
+module beside its configuration.
 
-Everything goes through the served path: ``BOService`` (the sync core:
-``submit_ask`` / ``service_step`` / ``submit_tell``) over a
-``FleetSampler`` over a ``FleetEngine``, with the write-ahead journal on.
+A deployment module defines:
+
+- ``Deployment(config, seed, run_dir)``: the service and its studies,
+  with ``order`` (every study, in the workers' order), ``n_studies``,
+  ``tenant_studies`` (each tenant's studies, in the configuration's
+  order of tenants), ``ask(study)`` (a service request handle),
+  ``tell(study, trial)``, ``serve_rounds(n)`` (set-up's whole rounds),
+  ``svc`` (whose ``service_step`` serves and whose ``stats_snapshot``
+  the counter metrics read), ``box(study) -> (lower, upper, dim)``,
+  ``ask_info(study)`` (the study's last suggest, as the program reported
+  it), ``journal_records()``, ``told`` (per study, every acknowledged
+  ``(trial id, x, y)``) and ``budget``;
+- ``window_room(config, told)``: the most asks a study may take in a
+  window that it starts with ``told`` trials told;
+- ``check_numbers(run, dtype, limits)``: the numbers ``correct``
+  compares.
 
 Set-up, in order, all of it counted in ``setup_s``: process start and
 imports, the persistent compile cache, every study grown to its starting
 n by random trials through the normal ask/tell calls, then two GP rounds:
 the cold full refit, which is the traffic's own state, and the first
 incremental round, which loads the last program the window uses.  Then
-the window: a fixed amount of work, ``window_asks`` GP asks of every
-study (the configuration sizes it to fit ``run_seconds``), from the
-window's start to its last completion; no clock cuts it short, so a
-slower program takes longer and never serves less.  Then, outside every
-metric, the check against ``bench/reference.py``.
+the window, a fixed amount of work from its start to its last
+completion; no clock cuts it short, so a slower program takes longer and
+never serves less:
+
+- closed loop: ``window_asks`` GP asks of every study, each issued by
+  the study's worker once it has told its last suggestion;
+- open loop: the mix's first ``arrivals`` Poisson arrivals, drawn from
+  the configuration's ``data_seed`` (``traffic.open_schedule``), the
+  window starting at the first.  Before each service step every arrival
+  that is due is submitted; after it every suggestion that came back is
+  told at once.  An ask's latency runs from its scheduled arrival.
+
+Then, outside every metric, the deployment's check against
+``bench/reference.py``.
 """
 from __future__ import annotations
 
 import importlib.util
 import json
 import math
+import re
 import shutil
 import sys
 import time
+from collections import Counter
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from bench import reference, traffic, tracing
-from bench.bbob import BBOBFunction
+from bench import traffic, tracing
 from bench.compile_meter import CompileMeter
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -46,7 +74,6 @@ GP_WARM_ROUNDS = 2                   # cold full refit + first incremental
 # ops a second) took 35-111 s for 1.5 s on the v5e, and the window waits
 # for it, so a traced run keeps to 0.5 s to stay well inside its time
 TRACE_SECONDS = 0.5
-REGRET_STARTS = 10                   # the reference's uniform restarts
 LATE_WAIT_S = 60.0                   # how long the window waits with no
                                      # answer before it gives an ask up
 
@@ -72,6 +99,7 @@ def load_cell(workload: str, spec: Dict) -> Dict:
         config = json.load(f)
     with open(BENCH / "traffic" / f"{cell['traffic']}.json") as f:
         mix = traffic.validate(json.load(f))
+    deployment_of(config)
 
     def mine(metrics):
         return [m for m in metrics
@@ -80,6 +108,26 @@ def load_cell(workload: str, spec: Dict) -> Dict:
     return {"cell": cell, "config": config, "mix": mix,
             "end_to_end": mine(spec["end_to_end"]),
             "per_layer": mine(spec["per_layer"])}
+
+
+def deployment_of(config: Dict):
+    """The module ``bench/deployments/<deployment>.py`` that the
+    configuration names, loaded once per process."""
+    name = config.get("deployment")
+    if not isinstance(name, str) or not re.fullmatch(r"[A-Za-z0-9_]+",
+                                                      name):
+        raise BenchError(f"the configuration names no deployment module: "
+                         f"'deployment' is {name!r}")
+    key = f"bench_deployment_{name}"
+    if key not in sys.modules:
+        path = BENCH / "deployments" / f"{name}.py"
+        if not path.is_file():
+            raise BenchError(f"no deployment module {path}")
+        spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[key] = mod
+    return sys.modules[key]
 
 
 def load_reader(metric: str) -> Callable:
@@ -100,7 +148,9 @@ class Ask:
     """One ask of the window, as its worker saw it."""
     study: int
     due: float                       # perf_counter when it was issued
-    req: object                      # the service's request handle
+                                     # (closed) or scheduled (open)
+    req: object                      # the service's request handle, or
+                                     # None where the service refused it
     done: Optional[float] = None     # perf_counter when it came back
     info: object = None              # the suggest's SuggestInfo
 
@@ -130,7 +180,7 @@ class Run:
     # per-step span metrics read only the steps that start after it
     span_from_us: float = 0.0
     device: Dict = field(default_factory=dict)
-    deployment: Optional["Deployment"] = None
+    deployment: object = None        # the deployment module's Deployment
 
     @property
     def latencies(self) -> List[float]:
@@ -151,13 +201,14 @@ class Run:
 
 
 def derive_seeds(seed: int) -> Dict[str, int]:
-    """Seeds below 2**30 for the program, the objectives and the order,
-    from any whole number."""
+    """Seeds below 2**30 for the program, the objectives, the order, the
+    reference's starts and an open mix's arrivals, from any whole
+    number."""
     ss = np.random.SeedSequence(seed % 2**63)
-    prog, obj, order, ref = (int(v) & 0x3FFFFFFF
-                             for v in ss.generate_state(4))
+    prog, obj, order, ref, arrivals = (int(v) & 0x3FFFFFFF
+                                       for v in ss.generate_state(5))
     return {"program": prog, "objectives": obj, "order": order,
-            "reference": ref}
+            "reference": ref, "arrivals": arrivals}
 
 
 def study_order(seed: int, n: int) -> List[int]:
@@ -181,86 +232,18 @@ def check_device(chips: int) -> None:
                          f"{len(devs)}")
 
 
-class Deployment:
-    """The configuration's service, studies, tenants and workers."""
-
-    def __init__(self, config: Dict, seed: int, run_dir: Path):
-        """The studies, their objectives and their random starting trials
-        come from the configuration's ``data_seed``: every run serves the
-        same set of studies, the same work, so that runs with different
-        seeds measure alike.  ``seed`` sets their order (tenant ownership
-        and the workers' order)."""
-        from repro.bo.sampler import FleetSampler
-        from repro.bo.space import BoxSpace
-        from repro.serve.bo_service import (BOService, OverloadConfig,
-                                            TenantConfig)
-        c = config
-        seeds = derive_seeds(int(c["data_seed"]))
-        self.dim = int(c["dim"])
-        self.lower, self.upper = float(c["lower"]), float(c["upper"])
-        self.budget = int(c["n_budget"])
-        n = int(c["studies"])
-        if not c["guarantees"]["journal"]:
-            raise BenchError("every configuration journals its asks and "
-                             "tells; the check reads them back")
-        self.journal_dir = run_dir / "journal"
-        self.objectives = [
-            BBOBFunction(c["objectives"][i % len(c["objectives"])],
-                         self.dim, seeds["objectives"] + i)
-            for i in range(n)]
-        self.fs = FleetSampler(
-            BoxSpace.cube(self.dim, self.lower, self.upper), n_studies=n,
-            seed=seeds["program"], slots=int(c["slots"]),
-            n_startup_trials=int(c["n_start"]),
-            n_restarts=int(c["n_restarts"]),
-            pad_multiple=int(c["pad_multiple"]),
-            refit_interval=int(c["refit_interval"]),
-            gp_fit_restarts=int(c["gp_fit_restarts"]),
-            posterior_backend="auto",
-            journal_dir=str(self.journal_dir))
-        self.order = study_order(seed, n)
-        tenants, self.owner, first = [], {}, 0
-        for t in c["tenants"]:
-            own = tuple(self.order[first:first + int(t["studies"])])
-            first += len(own)
-            tenants.append(TenantConfig(t["name"], weight=float(t["weight"]),
-                                        studies=own))
-            self.owner.update({s: t["name"] for s in own})
-        if first != n:
-            raise BenchError(f"tenants own {first} studies, config has {n}")
-        self.svc = BOService(self.fs, tenants, quantum=float(c["quantum"]),
-                             overload=OverloadConfig(reject_depth=2 * n,
-                                                     degrade_depth=4 * n,
-                                                     shed_depth=8 * n))
-        # per study, (trial id, x, y) of every tell, in order
-        self.told: List[List] = [[] for _ in range(n)]
-
-    @property
-    def n_studies(self) -> int:
-        return len(self.objectives)
-
-    def ask(self, study: int):
-        return self.svc.submit_ask(self.owner[study], study)
-
-    def tell(self, study: int, trial) -> None:
-        y = self.objectives[study](trial.x)
-        self.svc.submit_tell(self.owner[study], study, trial.trial_id, y)
-        self.told[study].append((trial.trial_id, np.array(trial.x), y))
-
-    def serve_rounds(self, n: int) -> None:
-        """Set-up rounds: every study asks, one service step serves them
-        all, every worker tells."""
-        for _ in range(n):
-            reqs = [(s, self.ask(s)) for s in self.order]
-            self.svc.service_step()
-            for s, req in reqs:
-                if req.state != "done":
-                    raise BenchError(f"set-up ask of study {s} ended "
-                                     f"{req.state}: {req.error}")
-                self.tell(s, req.result)
-
-    def wants_more(self, study: int) -> bool:
-        return len(self.told[study]) < self.budget
+def open_schedule(config: Dict, mix: Dict):
+    """An open mix's arrivals for this configuration's tenants, drawn from
+    its ``data_seed``: ``(t, tenant, position)``, ``t`` in seconds from
+    the first arrival, ``position`` the index among the tenant's
+    studies.  The same for every ``--seed``."""
+    tenants = [(float(t["weight"]), int(t["studies"]))
+               for t in config["tenants"]]
+    seed = derive_seeds(int(config["data_seed"]))["arrivals"]
+    try:
+        return traffic.open_schedule(mix, tenants, seed)
+    except ValueError as e:
+        raise BenchError(str(e)) from None
 
 
 def run_cell(config: Dict, mix: Dict, *, seed: int, trace: bool,
@@ -270,20 +253,27 @@ def run_cell(config: Dict, mix: Dict, *, seed: int, trace: bool,
     import jax
     from repro.obs import trace as obs
 
-    if mix["loop"] != "closed":
-        raise BenchError(f"no cell runs the {mix['loop']!r} loop yet")
-    k = int(config["window_asks"])
-    if int(config["n_start"]) + GP_WARM_ROUNDS + k > int(config["n_budget"]):
-        raise BenchError("the window's asks would run past the studies' "
-                         "trial budget")
+    deployment = deployment_of(config)
+    told = int(config["n_start"]) + GP_WARM_ROUNDS
+    if mix["loop"] == "closed":
+        schedule = None
+        most = int(config["window_asks"])
+    else:
+        schedule = open_schedule(config, mix)
+        most = max(Counter((k, i) for _, k, i in schedule).values())
+    room = deployment.window_room(config, told)
+    if most > room:
+        raise BenchError(f"the window asks up to {most} times of one "
+                         f"study, which would run past its trial budget "
+                         f"or its pad bucket ({room} asks fit)")
     shutil.rmtree(run_dir, ignore_errors=True)
     run_dir.mkdir(parents=True)
     meter.phase = "setup"
-    dep = Deployment(config, seed, run_dir)
-    dep.serve_rounds(int(config["n_start"]) + GP_WARM_ROUNDS)
+    dep = deployment.Deployment(config, seed, run_dir)
+    dep.serve_rounds(told)
 
     tracer = obs.enable(capacity=1 << 22) if trace else None
-    run = Run(setup_s=0.0, seed=seed)
+    run = Run(setup_s=0.0, seed=seed, deployment=dep)
     run.counters_start = dep.svc.stats_snapshot()
     # the profiler's op-level trace of a float64-emulated step runs to
     # ~650k device ops a second (minutes to write out a whole step), so
@@ -291,66 +281,12 @@ def run_cell(config: Dict, mix: Dict, *, seed: int, trace: bool,
     profile = _Profile(run_dir / "profile", tracer) if trace else None
 
     meter.phase = "window"
-    t0 = time.perf_counter()
-    run.t_start = t0
-    run.setup_s = t0 - t_process
-    pending: Dict[int, Ask] = {}
-    issued = {s: 0 for s in dep.order}
-
-    def issue(s: int) -> None:
-        issued[s] += 1
-        pending[s] = Ask(s, time.perf_counter(), dep.ask(s))
-
-    def step() -> None:
-        a = tracer.now_us() if tracer else 0.0
-        served = dep.svc.service_step()
-        if tracer:
-            tracer.record_span("bench.service_step", a, tracer.now_us() - a,
-                               served=served)
-        if served:
-            run.n_steps += 1
-        else:
-            time.sleep(0.01)          # nothing was due: do not spin
-
-    def collect() -> int:
-        t_done = time.perf_counter()
-        a = tracer.now_us() if tracer else 0.0
-        n = 0
-        for s in list(pending):
-            ask = pending[s]
-            if not ask.req.done:
-                continue
-            del pending[s]
-            run.asks.append(ask)
-            n += 1
-            if ask.req.state != "done":
-                continue
-            ask.done = t_done
-            ask.info = dep.fs.samplers[s].last_ask_info
-            dep.tell(s, ask.req.result)
-            if issued[s] < k:
-                issue(s)
-        if tracer:
-            tracer.record_span("bench.workers", a, tracer.now_us() - a)
-        return n
-
-    if profile is not None:
-        profile.arm()
-    for s in dep.order:
-        issue(s)
-    last = time.perf_counter()
-    while pending:
-        step()
-        if profile is not None:
-            # the next step starts once the profiler is stopped and its
-            # trace written: from there on the span metrics read
-            profile.wait()
-        if collect():
-            last = time.perf_counter()
-        elif time.perf_counter() - last > late_wait_s:
-            break                     # the rest is never answered
-    run.asks.extend(pending.values())
-    pending.clear()
+    window = _Window(run, dep, tracer, profile, t_process, late_wait_s)
+    if schedule is None:
+        window.closed(most)
+    else:
+        window.open([(t, dep.tenant_studies[k][i])
+                     for t, k, i in schedule])
     done = [a.done for a in run.asks if a.done is not None]
     run.t_end = max(done) if done else time.perf_counter()
     meter.phase = "after"
@@ -379,8 +315,146 @@ def run_cell(config: Dict, mix: Dict, *, seed: int, trace: bool,
             raise BenchError("the profiler trace lost its sync mark")
         run.trace_lo_ns = run.trace_offset_ns + 1e3 * lo_us
         run.trace_hi_ns = run.trace_offset_ns + 1e3 * hi_us
-    run.deployment = dep
     return run
+
+
+class _Window:
+    """The measured window's loop over one deployment: the closed loop's
+    workers or the open loop's schedule, one service step at a time."""
+
+    def __init__(self, run: Run, dep, tracer, profile, t_process: float,
+                 late_wait_s: float):
+        self.run, self.dep = run, dep
+        self.tracer, self.profile = tracer, profile
+        self.t_process = t_process
+        self.late_wait_s = late_wait_s
+
+    def start(self) -> float:
+        t0 = time.perf_counter()
+        self.run.t_start = t0
+        self.run.setup_s = t0 - self.t_process
+        if self.profile is not None:
+            self.profile.arm()
+        return t0
+
+    def step(self) -> float:
+        """One service step; then, in a traced run, the wait for the
+        profiler to stop and write its trace (the span metrics read from
+        there on).  Returns the seconds of that wait."""
+        tr = self.tracer
+        a = tr.now_us() if tr else 0.0
+        served = self.dep.svc.service_step()
+        if tr:
+            tr.record_span("bench.service_step", a, tr.now_us() - a,
+                           served=served)
+        if served:
+            self.run.n_steps += 1
+        else:
+            time.sleep(0.01)          # nothing was due: do not spin
+        if self.profile is None:
+            return 0.0
+        w = time.perf_counter()
+        self.profile.wait()
+        return time.perf_counter() - w
+
+    def finish(self, ask: Ask, t_done: float) -> bool:
+        """Record an ask that ended; tell its suggestion at once.  False
+        where it ended without one."""
+        self.run.asks.append(ask)
+        if ask.req.state != "done":
+            return False
+        ask.done = t_done
+        ask.info = self.dep.ask_info(ask.study)
+        self.dep.tell(ask.study, ask.req.result)
+        return True
+
+    def workers(self, collect: Callable[[float], int]) -> int:
+        t_done = time.perf_counter()
+        tr = self.tracer
+        a = tr.now_us() if tr else 0.0
+        n = collect(t_done)
+        if tr:
+            tr.record_span("bench.workers", a, tr.now_us() - a)
+        return n
+
+    def closed(self, k: int) -> None:
+        """Every study's worker asks ``k`` times, each ask once it has
+        told the last suggestion."""
+        dep = self.dep
+        pending: Dict[int, Ask] = {}
+        issued = {s: 0 for s in dep.order}
+
+        def issue(s: int) -> None:
+            issued[s] += 1
+            pending[s] = Ask(s, time.perf_counter(), dep.ask(s))
+
+        def collect(t_done: float) -> int:
+            n = 0
+            for s in list(pending):
+                ask = pending[s]
+                if not ask.req.done:
+                    continue
+                del pending[s]
+                n += 1
+                if self.finish(ask, t_done) and issued[s] < k:
+                    issue(s)
+            return n
+
+        self.start()
+        for s in dep.order:
+            issue(s)
+        last = time.perf_counter()
+        while pending:
+            self.step()
+            if self.workers(collect):
+                last = time.perf_counter()
+            elif time.perf_counter() - last > self.late_wait_s:
+                break                 # the rest is never answered
+        self.run.asks.extend(pending.values())
+
+    def open(self, schedule) -> None:
+        """Submit each ``(t, study)`` of the schedule once it is due, ``t``
+        seconds after the window's start, whatever the service's speed.
+        A traced run's wait for the profiler shifts the rest of the
+        schedule by as much, so the steps after it see the arrivals that
+        an untraced run sees."""
+        pending: List[Ask] = []
+
+        def collect(t_done: float) -> int:
+            done = [a for a in pending if a.req.done]
+            pending[:] = [a for a in pending if not a.req.done]
+            for ask in done:
+                self.finish(ask, t_done)
+            return len(done)
+
+        base = self.start()
+        nxt, last = 0, base
+        while nxt < len(schedule) or pending:
+            if not pending:
+                wait = base + schedule[nxt][0] - time.perf_counter()
+                if wait > 0.0:
+                    time.sleep(wait)
+                last = time.perf_counter()
+            now = time.perf_counter()
+            while nxt < len(schedule) and base + schedule[nxt][0] <= now:
+                t, s = schedule[nxt]
+                nxt += 1
+                try:
+                    req = self.dep.ask(s)
+                except RuntimeError:  # refused: counts as unserved
+                    self.run.asks.append(Ask(s, base + t, None))
+                    continue
+                pending.append(Ask(s, base + t, req))
+            if not pending:
+                continue
+            base += self.step()
+            if self.workers(collect):
+                last = time.perf_counter()
+            elif time.perf_counter() - last > self.late_wait_s:
+                break                 # the rest is never answered
+        self.run.asks.extend(pending)
+        self.run.asks.extend(Ask(s, base + t, None)
+                             for t, s in schedule[nxt:])
 
 
 class _Profile:
@@ -432,136 +506,6 @@ class _Profile:
 
 
 # ------------------------------------------------------------ the check
-def check_numbers(run: Run, dtype=np.float64,
-                  limits: Optional[Dict] = None) -> Dict[str, float]:
-    """The numbers ``correct`` compares, from what the window produced.
-
-    - ``unserved_asks``: acknowledged asks of the window that did not come
-      back with a finite suggestion inside the box;
-    - ``journal_missing``: acknowledged tells (set-up and window) and
-      delivered suggestions of the window that the journal does not give
-      back as they were acknowledged;
-    - ``logei_gap_nats``: over every suggestion of the window, the largest
-      gap between the LogEI the service's MSO reported at its suggestion
-      and the reference's LogEI there, for the GP the suggest saw;
-    - ``pgrad_inf``: over every suggestion, the largest infinity norm of
-      the projected gradient of the reference's −LogEI there, which the
-      MSO's stopping test holds under its ``pgtol`` (an MSO that stopped
-      early reads large);
-    - ``incumbent_excess_nats``: over every suggestion, the most by which
-      the reference's LogEI at the study's best observation exceeds its
-      LogEI at the suggestion (the MSO starts from that observation, so a
-      sound one never ends below it);
-    - ``regret_nats``: the mean over every suggestion of the LogEI that
-      the reference's own multistart L-BFGS-B (``REGRET_STARTS`` starts
-      drawn from the run's seed, the best observation and the suggestion)
-      finds above the suggestion's: an MSO with too few restarts.  Only
-      where ``limits`` compares it, or ``limits`` is not given;
-    - ``map_gain_nats``: over every full refit whose θ a suggestion of the
-      window used, how far the reference's L-BFGS-B still lowers the fit's
-      MAP objective from that θ, on the data the refit saw.
-
-    With ``dtype=np.float32`` the reference stands in for the program
-    (the control): its float32 LogEI against the float64 one.
-    """
-    dep = run.deployment
-    lo, hi = dep.lower, dep.upper
-    unserved = 0
-    for a in run.asks:
-        x = None if a.done is None else np.asarray(a.req.result.x)
-        if x is None or x.shape != (dep.dim,) or not np.all(np.isfinite(x)) \
-                or np.any(x < lo) or np.any(x > hi):
-            unserved += 1
-    records = reference.read_journal(str(dep.journal_dir / "journal.log"))
-    tells, asks_x, theta_at, theta, fit_of = {}, {}, {}, {}, {}
-    for r in records:
-        op = r.get("op")
-        if op == "tell":
-            tells[(r["study"], r["trial"])] = r["y"]
-        elif op == "refit":
-            theta[r["sid"]] = r["theta"]
-            fit_of[r["sid"]] = None          # fitted for the next ask
-        elif op == "ask":
-            s = r["study"]
-            asks_x[(s, r["trial"])] = r["x"]
-            if not r["startup"]:
-                if s in fit_of and fit_of[s] is None:
-                    fit_of[s] = r["trial"]       # the refit fed this ask
-                theta_at[(s, r["trial"])] = (theta.get(s), fit_of.get(s))
-    missing = 0
-    for s, seq in enumerate(dep.told):
-        for tid, _x, y in seq:
-            if tells.get((s, tid)) != y:
-                missing += 1
-
-    def observed(study: int, before: int):
-        prior = [(x, y) for tid, x, y in dep.told[study] if tid < before]
-        xo = (np.stack([p[0] for p in prior]) - lo) / (hi - lo)
-        return xo, np.array([p[1] for p in prior]), len(prior) == before
-
-    want_regret = limits is None or "regret_nats" in limits
-    starts_rng = np.random.default_rng(derive_seeds(run.seed)["reference"])
-    gap = pgrad = 0.0
-    regrets = []
-    excess = -math.inf                      # below 0 where every one beat it
-    fits = {}
-    for a in run.asks:
-        if a.done is None:
-            continue
-        t = a.req.result
-        key = (a.study, t.trial_id)
-        if asks_x.get(key) != list(map(float, t.x)):
-            missing += 1
-        th, fit_trial = theta_at.get(key, (None, None))
-        xo, yo, whole = observed(a.study, t.trial_id)
-        if th is None or a.info is None or not whole:
-            gap = pgrad = excess = math.inf
-            regrets.append(math.inf)
-            continue
-        fits[(a.study, fit_trial)] = th
-        xq = (np.asarray(t.x) - lo) / (hi - lo)
-        try:
-            logei = reference.logei_fn(xo, yo, th)
-            ref = logei(xq)[0]
-            got = (float(np.asarray(a.info.best_acq)) if dtype == np.float64
-                   else float(reference.logei_at(xo, yo, th, xq,
-                                                 dtype=dtype)[0]))
-            inc = logei(xo[np.argmin(yo)])[0]
-            pg = reference.projected_grad_inf(xo, yo, th, xq)
-            if want_regret:
-                regrets.append(reference.logei_regret(
-                    xo, yo, th, xq,
-                    starts_rng.random((REGRET_STARTS, dep.dim))))
-        except np.linalg.LinAlgError:      # K not positive definite
-            gap = pgrad = excess = math.inf
-            regrets.append(math.inf)
-            continue
-        gap = max(gap, _finite(abs(got - ref)))
-        pgrad = max(pgrad, _finite(pg))
-        excess = max(excess, _finite(inc - ref))
-    map_gain = 0.0
-    for (study, fit_trial), th in fits.items():
-        xo, yo, _ = observed(study, fit_trial)
-        try:
-            map_gain = max(map_gain,
-                           _finite(reference.map_polish(xo, yo, th)))
-        except np.linalg.LinAlgError:
-            map_gain = math.inf
-    out = {"unserved_asks": float(unserved),
-           "journal_missing": float(missing),
-           "logei_gap_nats": gap,
-           "pgrad_inf": pgrad,
-           "incumbent_excess_nats": excess if run.n_completed else 0.0,
-           "map_gain_nats": map_gain}
-    if want_regret:
-        out["regret_nats"] = _finite(np.mean(regrets)) if regrets else 0.0
-    return out
-
-
-def _finite(v: float) -> float:
-    return float(v) if np.isfinite(v) else math.inf
-
-
 def judge(numbers: Dict[str, float], limits: Dict[str, float]) -> Dict:
     """Each number that has a limit beside it; a number is within it when
     it is finite and no larger."""
@@ -664,6 +608,7 @@ def measure(args, t_process: float, run_dir: Path):
                        trace=bool(args.trace), meter=meter,
                        t_process=t_process, run_dir=run_dir)
     limits = cell["config"]["limits"]
-    checks = judge(check_numbers(run, limits=limits), limits)
+    check = deployment_of(cell["config"]).check_numbers
+    checks = judge(check(run, limits=limits), limits)
     metrics = cell["per_layer"] if args.trace else cell["end_to_end"]
     return result_line(run, metrics, bool(args.trace), checks), run

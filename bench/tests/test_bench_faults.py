@@ -14,8 +14,10 @@ sound or broken underneath, and reads ``correct``:
 - an MSO whose restarts all start from the best observation;
 - a MAP refit that stops after one iteration.
 
+Each fault is planted under the closed loop and under the open loop.
 And the window is a fixed amount of work: a uniformly slower service
-step never improves the rate or the tail.
+step never improves the rate or the tail, in either loop.  An open-loop
+run answers every arrival of its schedule, which no ``--seed`` moves.
 """
 from __future__ import annotations
 
@@ -31,15 +33,20 @@ from bench.compile_meter import CompileMeter
 
 FIXTURES = Path(__file__).resolve().parent / "fixtures"
 MIX = {"loop": "closed"}
+# 12 asks at 4 a second: 2/3 of them from t0, whose 2 studies get at most
+# 5 each, inside tiny's budget and tiny_dense's bucket
+OPEN_MIX = {"loop": "open", "rate_per_s": 4.0, "tenant_shares": "zipf",
+            "zipf_s": 1.0, "arrivals": 12}
+BOX = harness.deployment_of({"deployment": "bbob_box"})
 
 
 def _config(name):
     return json.loads((FIXTURES / f"{name}.json").read_text())
 
 
-def _run(tmp_path, config, seed=11):
+def _run(tmp_path, config, seed=11, mix=MIX):
     with CompileMeter() as meter:
-        run = harness.run_cell(config, MIX, seed=seed, trace=False,
+        run = harness.run_cell(config, mix, seed=seed, trace=False,
                                meter=meter,
                                t_process=time.perf_counter(),
                                run_dir=tmp_path / "run", late_wait_s=1.0)
@@ -48,7 +55,7 @@ def _run(tmp_path, config, seed=11):
 
 def _line(run, config, dtype=np.float64):
     checks = harness.judge(
-        harness.check_numbers(run, dtype=dtype, limits=config["limits"]),
+        BOX.check_numbers(run, dtype=dtype, limits=config["limits"]),
         config["limits"])
     metrics = [{"name": "ask_p50_s", "unit": "s"},
                {"name": "setup_s", "unit": "s"}]
@@ -119,11 +126,11 @@ def _unfitted(orig):
     return refit_core
 
 
-@pytest.mark.parametrize("fault", ["stale_state", "half_dropped",
-                                   "altered_answer", "lost_tell",
-                                   "capped_mso", "one_restart",
-                                   "unfitted_theta"])
-def test_fault_makes_run_incorrect(tmp_path, monkeypatch, fault):
+FAULTS = ["stale_state", "half_dropped", "altered_answer", "lost_tell",
+          "capped_mso", "one_restart", "unfitted_theta"]
+
+
+def _plant(monkeypatch, fault):
     import repro.engine.fleet as fleet
     from repro.bo.journal import StudyJournal
     from repro.engine.fleet import FleetEngine
@@ -147,16 +154,29 @@ def test_fault_makes_run_incorrect(tmp_path, monkeypatch, fault):
                             _tell_not_journaled(StudyJournal.append))
     else:
         # set-up serves whole rounds; the window loses half of each step
-        serve_rounds = harness.Deployment.serve_rounds
+        serve_rounds = BOX.Deployment.serve_rounds
 
         def serve_then_break(self, n):
             serve_rounds(self, n)
             monkeypatch.setattr(BOService, "_dispatch",
                                 _half_dispatch(BOService._dispatch))
-        monkeypatch.setattr(harness.Deployment, "serve_rounds",
+        monkeypatch.setattr(BOX.Deployment, "serve_rounds",
                             serve_then_break)
+
+
+@pytest.mark.parametrize("fault", FAULTS)
+def test_fault_makes_run_incorrect(tmp_path, monkeypatch, fault):
+    _plant(monkeypatch, fault)
     config = _config("tiny")
     line = _line(_run(tmp_path, config), config)
+    assert not line["correct"], line["checks"]
+
+
+@pytest.mark.parametrize("fault", FAULTS)
+def test_fault_makes_open_run_incorrect(tmp_path, monkeypatch, fault):
+    _plant(monkeypatch, fault)
+    config = _config("tiny")
+    line = _line(_run(tmp_path, config, mix=OPEN_MIX), config)
     assert not line["correct"], line["checks"]
 
 
@@ -179,3 +199,56 @@ def test_slower_step_never_improves_rate_or_tail(tmp_path, monkeypatch):
     assert read["asks_per_s"](slow) < read["asks_per_s"](fast)
     assert read["ask_p95_s"](slow) > read["ask_p95_s"](fast)
     assert read["ask_p50_s"](slow) > read["ask_p50_s"](fast)
+
+
+def test_slower_step_raises_open_loop_latency(tmp_path, monkeypatch):
+    """The same schedule, once with every service step 0.3 s slower: an
+    ask's latency runs from its scheduled arrival, so the queueing the
+    slow steps build up shows in both latencies."""
+    from repro.serve.bo_service import BOService
+    config = _config("tiny")
+    read = {m: harness.load_reader(m) for m in ("ask_p95_s", "ask_p50_s")}
+    fast = _run(tmp_path / "fast", config, mix=OPEN_MIX)
+    step = BOService.service_step
+
+    def slow_step(self, *args, **kw):
+        time.sleep(0.3)
+        return step(self, *args, **kw)
+    monkeypatch.setattr(BOService, "service_step", slow_step)
+    slow = _run(tmp_path / "slow", config, mix=OPEN_MIX)
+    assert slow.n_completed == fast.n_completed == OPEN_MIX["arrivals"]
+    assert read["ask_p95_s"](slow) > read["ask_p95_s"](fast)
+    assert read["ask_p50_s"](slow) > read["ask_p50_s"](fast)
+
+
+@pytest.mark.parametrize("seed", [11, 2**31 + 5])
+def test_open_run_answers_every_arrival_on_the_schedule(tmp_path, seed):
+    """Every arrival is answered, at the schedule's times whatever the
+    seed, and the check holds."""
+    config = _config("tiny")
+    run = _run(tmp_path, config, seed=seed, mix=OPEN_MIX)
+    line = _line(run, config)
+    assert line["correct"], line["checks"]
+    assert line["attempted"] == OPEN_MIX["arrivals"] == run.n_completed
+    assert run.window_compiles == 0
+    times = [t for t, _, _ in harness.open_schedule(config, OPEN_MIX)]
+    assert sorted(a.due - run.t_start for a in run.asks) == \
+        pytest.approx(times, abs=1e-9)
+    # no suggestion comes back before its ask arrived
+    assert all(a.done >= a.due for a in run.asks)
+
+
+def test_open_float32_control_is_not_correct(tmp_path):
+    config = _config("tiny_dense")
+    run = _run(tmp_path, config, mix=OPEN_MIX)
+    assert _line(run, config)["correct"]
+    control = _line(run, config, dtype=np.float32)
+    assert not control["correct"], control["checks"]
+
+
+def test_open_arrivals_past_the_budget_are_refused(tmp_path):
+    config = _config("tiny")
+    mix = dict(OPEN_MIX, arrivals=60)
+    with pytest.raises(harness.BenchError, match="budget"):
+        _run(tmp_path, config, mix=mix)
+    assert not (tmp_path / "run").exists()    # refused before set-up

@@ -326,6 +326,56 @@ def test_cell_found_by_name(workload):
     assert cell["per_layer"]
 
 
+@pytest.mark.parametrize("config", [{}, {"deployment": "no_such_shape"},
+                                    {"deployment": "../configs/x"},
+                                    {"deployment": 3}])
+def test_config_without_a_deployment_module_is_refused(config):
+    with pytest.raises(harness.BenchError, match="deployment"):
+        harness.deployment_of(config)
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in SPEC["configs"]])
+def test_committed_config_loads_bbob_box(name):
+    entry = next(c for c in SPEC["configs"] if c["name"] == name)
+    config = json.loads((ROOT / entry["file"]).read_text())
+    assert config["deployment"] == "bbob_box"
+    mod = harness.deployment_of(config)
+    assert mod is harness.deployment_of({"deployment": "bbob_box"})
+    for attr in ("Deployment", "check_numbers", "window_room"):
+        assert callable(getattr(mod, attr))
+    # set-up leaves each study with n_start + GP_WARM_ROUNDS trials; the
+    # closed window's asks fit what the deployment allows
+    told = int(config["n_start"]) + harness.GP_WARM_ROUNDS
+    assert 0 < int(config["window_asks"]) <= mod.window_room(config, told)
+
+
+def test_window_room_is_the_budget_or_the_bucket():
+    room = harness.deployment_of({"deployment": "bbob_box"}).window_room
+    assert room({"pad_multiple": 32, "n_budget": 64}, 35) == 29
+    assert room({"pad_multiple": 32, "n_budget": 300}, 35) == 29
+    assert room({"pad_multiple": 32, "n_budget": 50}, 35) == 15
+    assert room({"pad_multiple": 16, "n_budget": 40}, 35) == 5
+    assert room({"pad_multiple": 32, "n_budget": 300}, 64) == 0
+
+
+def test_open_schedule_is_fixed_work_from_the_data_seed():
+    config = json.loads((ROOT / "bench" / "configs" / "bbob_d5_s64.json")
+                        .read_text())
+    mix = {"loop": "open", "rate_per_s": 10.0, "tenant_shares": "zipf",
+           "zipf_s": 1.0, "arrivals": 400}
+    sched = harness.open_schedule(config, mix)
+    assert len(sched) == 400 and sched[0][0] == 0.0
+    assert all(a[0] <= b[0] for a, b in zip(sched, sched[1:]))
+    assert sched == harness.open_schedule(config, mix)
+    assert sched != harness.open_schedule(dict(config, data_seed=1), mix)
+    # Zipf s=1 over 4 tenants: shares 12/25, 6/25, 4/25, 3/25
+    n0 = sum(k == 0 for _, k, _ in sched)
+    assert abs(n0 - 192) < 5 * math.sqrt(192)
+    assert {i for _, k, i in sched if k == 0} == set(range(16))
+    with pytest.raises(ValueError):
+        traffic.validate(dict(mix, arrivals=0))
+
+
 def test_unknown_workload_is_refused():
     with pytest.raises(harness.BenchError):
         harness.load_cell("no_such.cell", SPEC)

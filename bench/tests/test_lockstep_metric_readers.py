@@ -100,3 +100,38 @@ def test_stage_and_request_span_metrics():
 def test_stage_and_request_readers_return_nothing_without_spans(metric):
     assert harness.load_reader(metric)(
         harness.Run(setup_s=0.0, n_steps=3)) is None
+
+
+def test_open_loop_readers():
+    """``mso_ms_per_step_open`` reads what ``mso_ms_per_step`` reads;
+    ``asks_per_step_open`` the asks each clean step served."""
+    spans = []
+    for k in range(3):               # 3 steps, MSO 400, 500, 600 us
+        t = 2000.0 * k
+        spans += [dict(_span("bench.service_step", t, 1000.0),
+                       args={"served": 5 + k}),
+                  _span("fleet.step", t + 100.0, 800.0),
+                  _span("fleet.program.mso", t + 200.0, 400.0 + 100.0 * k)]
+    # a step that served nothing is no step of the batch
+    spans.append(dict(_span("bench.service_step", 6000.0, 10.0),
+                      args={"served": 0}))
+    run = harness.Run(setup_s=0.0, spans=spans, n_steps=3)
+    mso = harness.load_reader("mso_ms_per_step_open")
+    batch = harness.load_reader("asks_per_step_open")
+    assert mso(run) == harness.load_reader("mso_ms_per_step")(run) == \
+        pytest.approx(0.5)
+    assert batch(run) == 6.0
+    late = harness.Run(setup_s=0.0, spans=spans, n_steps=3,
+                       span_from_us=1500.0)
+    assert mso(late) == pytest.approx(0.55)
+    assert batch(late) == 6.5
+
+
+@pytest.mark.parametrize("metric", ["mso_ms_per_step_open",
+                                    "asks_per_step_open"])
+def test_open_loop_readers_read_nothing_without_spans(metric):
+    read = harness.load_reader(metric)
+    assert read(harness.Run(setup_s=0.0, n_steps=3)) is None
+    # a window in which no step served reads nothing either
+    idle = [dict(_span("bench.service_step", 0.0, 10.0), args={"served": 0})]
+    assert read(harness.Run(setup_s=0.0, spans=idle, n_steps=0)) is None
